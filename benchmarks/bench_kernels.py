@@ -1,5 +1,8 @@
-"""Kernel micro-benchmarks (CPU interpret mode = correctness-path timing; the
-numbers of record on real TPU come from the same harness with interpret=False).
+"""Kernel micro-benchmarks in Pallas interpret mode on the CPU.
+
+These rows time the interpreter and the numpy reference, not a device: they
+are correctness-path timings. ``chip_smoke.py`` runs the compiled kernel on
+a TPU.
 """
 
 from __future__ import annotations
@@ -40,7 +43,9 @@ def run() -> list[dict]:
             for v in res.mapping.dfg.nodes
             if res.mapping.dfg.ops[v] == "input"
         }
-        us = _time_call(lambda: cgra_run(prog, inputs, 8, batch_tile=64)[0])
+        us = _time_call(
+            lambda: cgra_run(prog, inputs, 8, batch_tile=64, interpret=True)[0]
+        )
         rows.append({"name": f"cgra_sim_pallas_b{batch}", "us_per_call": round(us, 1),
                      "derived": f"II={prog.ii},ring={prog.ring}"})
         us_ref = _time_call(lambda: cgra_sim_reference(prog, inputs, 8)[0])

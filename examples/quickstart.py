@@ -15,13 +15,14 @@ run is served from disk instead of re-solved.
 
 import argparse
 
+import jax
 import numpy as np
 
 from repro import obs
 from repro.api import Compiler, add_cli_args, options_from_args
 from repro.core import CGRA, running_example
 from repro.core.simulate import check_equivalence
-from repro.kernels.ops import cgra_run, compile_program
+from repro.kernels.ops import cgra_run, compile_program, enable_compile_cache
 
 ap = argparse.ArgumentParser()
 add_cli_args(ap)                      # --profile/--cache-dir/--deterministic/...
@@ -50,7 +51,12 @@ report = check_equivalence(m, num_iters=8)
 print(f"functional equivalence OK over {report.cycles} cycles; "
       f"max register pressure {max(report.max_register_pressure.values())}")
 
-# 4. run 256 independent instances of the loop through the Pallas CGRA kernel
+# 4. run 256 independent instances of the loop through the Pallas CGRA kernel:
+# compiled on a TPU, in the Pallas interpreter anywhere else
+enable_compile_cache()
+interpret = jax.default_backend() != "tpu"
+print(f"cgra_sim on {jax.devices()[0].device_kind}: "
+      f"{'interpret mode' if interpret else 'compiled'}")
 prog = compile_program(m)
 rng = np.random.default_rng(0)
 inputs = {
@@ -58,7 +64,7 @@ inputs = {
     for v in dfg.nodes
     if dfg.ops[v] == "input"
 }
-outs, trace = cgra_run(prog, inputs, num_iters=8)
+outs, trace = cgra_run(prog, inputs, num_iters=8, interpret=interpret)
 # read the sink node's stream from the trace (the running example's final op)
 sink = max(dfg.nodes, key=lambda v: m.t_abs[v])
 cycles = [m.t_abs[sink] + it * m.ii for it in range(8)]

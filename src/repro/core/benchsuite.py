@@ -20,7 +20,7 @@ from __future__ import annotations
 import random
 import zlib
 
-from .dfg import DFG, Edge
+from .dfg import DFG, OP_ARITY, Edge
 
 # name -> (num_nodes, rec_ii) per Table III (RecII derived from large-grid mII)
 TABLE3_BENCHMARKS: dict[str, tuple[int, int]] = {
@@ -136,6 +136,26 @@ def route_stress_dfg() -> DFG:
         imms=[0.0, 0.0, 3.0, 0.0, 0.0],
         name="route_stress",
     )
+
+
+def opcode_cover_dfg() -> DFG:
+    """A chain through every ALU opcode, each binary op also reading one of
+    two inputs: the kernel executor's op-coverage case (the suite has no
+    ``div`` or ``cmp``). Used by the cgra_sim tests and ``chip_smoke.py``."""
+    mid = ["add", "sub", "mul", "div", "min", "max", "neg", "abs", "mov",
+           "cmp", "and", "or", "xor", "shl", "shr", "not"]
+    ops = ["input", "input", "const"] + mid + ["store"]
+    edges = []
+    prev = 2  # const feeds the first op
+    for v in range(3, 3 + len(mid)):
+        edges.append(Edge(prev, v))
+        if OP_ARITY[ops[v]] == 2:
+            edges.append(Edge(v % 2, v))  # alternate the two inputs
+        prev = v
+    edges.append(Edge(prev, len(ops) - 1))
+    dfg = DFG(num_nodes=len(ops), edges=edges, ops=ops, name="opcover")
+    dfg.validate()
+    return dfg
 
 
 def load_suite(names: list[str] | None = None) -> dict[str, DFG]:

@@ -4,10 +4,9 @@ The fuzz suite (``tests/test_differential.py``, DESIGN.md §14.5) needs a
 stream of small, *valid* DFGs that exercise every structural feature the
 mapper handles — fan-out, reconvergence, loop-carried recurrences, memory
 ops, multiplies — without ever producing an input ``DFG.validate`` would
-reject. ``hypothesis`` is not available in the container, so generation is
-a plain seeded :class:`random.Random` walk: ``random_dfg(seed)`` is a pure
-function of its arguments, which makes every fuzz failure replayable from
-the seed printed in the test id.
+reject. Generation is a plain seeded :class:`random.Random` walk:
+``random_dfg(seed)`` is a pure function of its arguments, which makes every
+fuzz failure replayable from the seed printed in the test id.
 
 Construction invariants (each is load-bearing for validity):
 

@@ -16,6 +16,7 @@ register-pressure probe (paper §V-3 assumes enough registers; we measure).
 
 from __future__ import annotations
 
+import ctypes
 import math
 from dataclasses import dataclass
 
@@ -36,13 +37,26 @@ OPCODES: dict[str, int] = {
 }
 
 
-def alu(op: str, a: float, b: float, imm: float) -> float:
-    """Scalar ALU semantics, float domain.
+def f32(x: float) -> float:
+    """``x`` rounded to the nearest float32, the datapath's word (overflow
+    gives inf, as on the device)."""
+    return ctypes.c_float(x).value
 
-    Bitwise ops work on 16-bit casts of |x| so results are exactly
-    representable in float32 — keeping this oracle bit-identical to the
-    vectorised Pallas kernel (kernels/cgra_sim.py), which computes in f32.
+
+def alu(op: str, a: float, b: float, imm: float) -> float:
+    """Scalar ALU semantics on float32 words: the result is rounded to f32.
+
+    Python computes in float64; rounding each result once gives the
+    correctly rounded f32 result of +, -, *, / (53 >= 2*24 + 2 bits), so
+    this oracle is bit-identical to the vectorised Pallas kernel
+    (kernels/cgra_sim.py), which computes in f32. Without the rounding a
+    value can land just below an integer in f64 and on it in f32, and the
+    bitwise ops, which work on 16-bit casts of |x|, then differ by one.
     """
+    return f32(_alu64(op, a, b, imm))
+
+
+def _alu64(op: str, a: float, b: float, imm: float) -> float:
     ia, ib = int(abs(a)) & 0xFFFF, int(abs(b)) & 0xFFFF
     if op in ("input", "const"):
         return imm
@@ -105,10 +119,10 @@ def interpret_dfg(
         for v in order:
             op = dfg.ops[v]
             if op == "input":
-                cur[v] = inputs[v][it]
+                cur[v] = f32(inputs[v][it])
                 continue
             if op == "const":
-                cur[v] = dfg.imms[v]
+                cur[v] = f32(dfg.imms[v])
                 continue
             args: list[float] = []
             for e in _operands(dfg, v):
@@ -191,9 +205,9 @@ def execute_mapping(
             op = dfg.ops[v]
             pe = placement[v]
             if op == "input":
-                val = inputs[v][it]
+                val = f32(inputs[v][it])
             elif op == "const":
-                val = dfg.imms[v]
+                val = f32(dfg.imms[v])
             else:
                 args: list[float] = []
                 for e in _operands(dfg, v):

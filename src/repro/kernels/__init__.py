@@ -10,5 +10,7 @@ flash_attention.py   fused attention (causal/sliding-window/softcap/GQA) —
 ops.py               program compilation + jit'd wrappers
 ref.py               pure-jnp / numpy oracles (kernels assert against these)
 
-Validated with interpret=True on CPU; pass interpret=False on real TPUs.
+The kernels run compiled for the TPU by default. The tests force the CPU
+(JAX_PLATFORMS=cpu) and pass interpret=True; chip_smoke.py at the repo root
+runs the compiled cgra_sim path on a chip.
 """

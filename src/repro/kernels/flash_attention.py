@@ -12,10 +12,10 @@ the kv dimension innermost/sequential; running max / denominator / accumulator
 live in VMEM scratch in f32. Block shapes default to (128, 128) so the
 q-block x d and kv-block x d tiles are MXU-aligned.
 
-On this CPU container the kernel is validated with interpret=True against
-ref.reference_attention; on TPU pass interpret=False. The model zoo uses the
-pure-jnp reference by default (portable + SPMD-partitionable); this kernel is
-the TPU hot-path drop-in.
+The tests validate the kernel on the CPU with interpret=True against
+ref.reference_attention; it runs compiled on a TPU by default. The model
+zoo uses the pure-jnp reference by default (portable + SPMD-partitionable);
+this kernel is the TPU hot-path drop-in.
 """
 
 from __future__ import annotations

@@ -5,12 +5,15 @@ dense one-hot tables the cgra_sim kernel consumes — the step where the CGRA's
 crossbar and opcode decoders become MXU/VPU-friendly tensors (DESIGN.md §3).
 
 ``cgra_run`` executes a compiled program over batched input streams and
-returns per-store-node outputs, via the Pallas kernel (interpret=True on CPU).
+returns per-store-node outputs, via the Pallas kernel. It runs compiled for
+the TPU by default; CPU callers (the tests) pass ``interpret=True``.
 """
 
 from __future__ import annotations
 
+import os
 from dataclasses import dataclass
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -20,7 +23,7 @@ from repro.core.dfg import DFG
 from repro.core.mapper import Mapping
 from repro.core.simulate import OPCODES, _operands
 
-from .cgra_sim import KERNEL_OPS, NOPS, cgra_sim_pallas
+from .cgra_sim import KERNEL_OPS, NOPS, cgra_sim_pallas, vmem_footprint
 
 assert list(KERNEL_OPS) == list(OPCODES), "kernel/oracle opcode tables diverged"
 
@@ -45,9 +48,23 @@ class CGRAProgram:
     src_delta: np.ndarray  # [II, pes, 2] int32 (cycles since operand produced)
 
     def vmem_bytes(self, batch_tile: int) -> int:
-        route = 2 * self.ii * self.num_pes * self.ring * self.num_pes * 4
-        state = self.ring * self.num_pes * batch_tile * 4
-        return route + state
+        """VMEM one kernel grid step needs (the kernel's ``vmem_limit_bytes``)."""
+        return vmem_footprint(self.num_pes, self.ring, batch_tile)
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    Where ``$JAX_COMPILATION_CACHE_DIR`` is set, JAX keeps the cache there and
+    this sets nothing. Otherwise the cache goes to ``.jax_cache`` at the root
+    of this checkout: a fixed path, so a later run finds what an earlier one
+    compiled. Call it before the first compile; importing does not.
+    """
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = str(Path(__file__).resolve().parents[3] / ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def compile_program(mapping: Mapping) -> CGRAProgram:
@@ -120,33 +137,48 @@ def build_injection(
     return inj, active
 
 
+def cgra_launch(
+    program: CGRAProgram,
+    inj: np.ndarray | jax.Array,      # [C, pes, B] f32 (build_injection)
+    active: np.ndarray | jax.Array,   # [C, pes] f32
+    *,
+    batch_tile: int = 128,
+    interpret: bool = False,
+) -> jax.Array:
+    """Launch the kernel on staged injections; returns the device trace [C, pes, B]."""
+    C, pes, batch = inj.shape
+    return cgra_sim_pallas(
+        jnp.asarray(program.route_a),
+        jnp.asarray(program.route_b),
+        jnp.asarray(program.op_sel),
+        jnp.asarray(program.imm.reshape(program.ii, 1, pes)),
+        jnp.asarray(inj),
+        jnp.asarray(active.reshape(C, 1, pes)),
+        ii=program.ii,
+        ring=program.ring,
+        num_cycles=C,
+        batch_tile=min(batch_tile, batch),
+        interpret=interpret,
+    )
+
+
 def cgra_run(
     program: CGRAProgram,
     inputs: dict[int, np.ndarray],   # input node -> [num_iters, B] f32
     num_iters: int,
     *,
     batch_tile: int = 128,
-    interpret: bool = True,          # CPU container: interpret; TPU: False
+    interpret: bool = False,
 ) -> tuple[dict[int, np.ndarray], np.ndarray]:
-    """Execute on the Pallas kernel; returns (store outputs, full trace)."""
+    """Execute on the Pallas kernel; returns (store outputs, full trace).
+
+    Compiled for the TPU unless ``interpret=True``; on a CPU backend the
+    compiled path raises rather than falling back to the interpreter.
+    """
     inj, active = build_injection(program, inputs, num_iters)
-    C = inj.shape[0]
-    batch = inj.shape[2]
-    bt = min(batch_tile, batch)
-    trace = cgra_sim_pallas(
-        jnp.asarray(program.route_a),
-        jnp.asarray(program.route_b),
-        jnp.asarray(program.op_sel),
-        jnp.asarray(program.imm),
-        jnp.asarray(inj),
-        jnp.asarray(active),
-        ii=program.ii,
-        ring=program.ring,
-        num_cycles=C,
-        batch_tile=bt,
-        interpret=interpret,
+    trace = np.asarray(
+        cgra_launch(program, inj, active, batch_tile=batch_tile, interpret=interpret)
     )
-    trace = np.asarray(trace)
     m = program.mapping
     outs: dict[int, np.ndarray] = {}
     for v in m.dfg.nodes:

@@ -21,7 +21,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..compat import shard_map
 from . import attention as attn_mod
 from .api import ArchConfig, ModelSpec
 from .attention import (
@@ -130,7 +129,7 @@ def block_apply(
                 },
                 x_spec,
             )
-            f, aux = shard_map(
+            f, aux = jax.shard_map(
                 wrapped, mesh=mesh, in_specs=specs_in,
                 out_specs=(out_spec, P()),
             )(p["moe"], h)

@@ -25,7 +25,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from ..compat import axis_size
 from .layers import dense_param
 
 
@@ -99,9 +98,9 @@ def moe_ffn(
         axes = model_axis if isinstance(model_axis, tuple) else (model_axis,)
         n_shards, shard = 1, 0
         for a in axes:
-            n_shards = n_shards * axis_size(a)
+            n_shards = n_shards * jax.lax.axis_size(a)
         for a in axes:
-            shard = shard * axis_size(a) + jax.lax.axis_index(a)
+            shard = shard * jax.lax.axis_size(a) + jax.lax.axis_index(a)
     else:
         n_shards, shard = 1, 0
     e_loc = params["expert_up"].shape[0]                     # E/shards (sharded in)

@@ -1,9 +1,9 @@
 """Differential fuzz harness: portfolio vs exact joint backend (DESIGN.md §14.5).
 
-``hypothesis`` is not available in the container, so this is a deterministic
-fuzz suite: :func:`repro.core.fuzz.random_dfg` turns a seed into a small
-valid DFG, and every case id embeds the seed, fabric, and backend, so any
-failure is replayable verbatim. The default budget is ``FUZZ_SEEDS`` seeds ×
+This is a deterministic fuzz suite, not a ``hypothesis`` one:
+:func:`repro.core.fuzz.random_dfg` turns a seed into a small valid DFG, and
+every case id embeds the seed, fabric, and backend, so any failure is
+replayable verbatim. The default budget is ``FUZZ_SEEDS`` seeds ×
 6 (fabric, space-backend) configs ≥ 200 mapped cases; the nightly CI job
 raises it via ``REPRO_FUZZ_CASES``.
 

@@ -183,7 +183,8 @@ def test_routed_mapping_through_pallas_program():
     num_iters, batch = 5, 8
     rng = np.random.default_rng(0)
     inputs = {0: rng.uniform(-4, 4, (num_iters, batch)).astype(np.float32).round(2)}
-    outs, _trace = cgra_run(prog, inputs, num_iters, batch_tile=batch)
+    outs, _trace = cgra_run(prog, inputs, num_iters, batch_tile=batch,
+                            interpret=True)
     ref = interpret_dfg(
         dfg, {0: [float(x) for x in inputs[0][:, 0]]}, num_iters
     )

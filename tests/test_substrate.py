@@ -73,7 +73,6 @@ def test_compressed_pod_reduction_numerics_and_wire():
         import os
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import jax, jax.numpy as jnp, numpy as np
-        from repro.compat import shard_map
         from repro.optim.compression import compressed_psum_mean
 
         mesh = jax.make_mesh((2, 4), ("pod", "data"))
@@ -84,8 +83,8 @@ def test_compressed_pod_reduction_numerics_and_wire():
             return compressed_psum_mean(g, "pod")   # slow DCI hop, int8 wire
 
         f = jax.jit(
-            shard_map(reduce_fn, mesh=mesh, in_specs=P(("pod", "data")),
-                      out_specs=P(("pod", "data")))
+            jax.shard_map(reduce_fn, mesh=mesh, in_specs=P(("pod", "data")),
+                          out_specs=P(("pod", "data")))
         )
         rng = np.random.default_rng(0)
         x = jnp.asarray(rng.standard_normal((8, 1024)), jnp.float32)
