@@ -237,44 +237,47 @@ class Compiler:
             CompileJob(dfg, self.cgra, name=name)
             for dfg, name in zip(dfgs, names)
         ]
-        t0 = _time.perf_counter()
-        # cross-process span shards (DESIGN.md §15.2): pool workers append
-        # per-pid shard files into a scratch dir that we merge back into this
-        # process's tracer; the inline path (jobs<=1) records directly into
-        # the active tracer and writes no shards
-        tracer = obs.get_tracer()
-        trace_tmp = (tempfile.TemporaryDirectory(prefix="repro-spans-")
-                     if tracer is not None else None)
-        try:
-            report = compile_many(
-                batch,
-                jobs=opts.jobs,
-                deterministic=opts.deterministic,
-                cache_dir=opts.cache_dir,
-                use_cache=opts.use_cache,
-                cancel=cancel,
-                map_options=opts.batch_kwargs(),
-                trace_dir=trace_tmp.name if trace_tmp is not None else None,
+        # the batch's own span: the merged trace then always holds this
+        # process's track, whichever workers ran the jobs
+        with obs.span("compile_batch", jobs=opts.jobs, kernels=len(batch)):
+            t0 = _time.perf_counter()
+            # cross-process span shards (DESIGN.md §15.2): pool workers
+            # append per-pid shard files into a scratch dir that we merge
+            # back into this process's tracer; the inline path (jobs<=1)
+            # records directly into the active tracer and writes no shards
+            tracer = obs.get_tracer()
+            trace_tmp = (tempfile.TemporaryDirectory(prefix="repro-spans-")
+                         if tracer is not None else None)
+            try:
+                report = compile_many(
+                    batch,
+                    jobs=opts.jobs,
+                    deterministic=opts.deterministic,
+                    cache_dir=opts.cache_dir,
+                    use_cache=opts.use_cache,
+                    cancel=cancel,
+                    map_options=opts.batch_kwargs(),
+                    trace_dir=trace_tmp.name if trace_tmp is not None else None,
+                )
+            finally:
+                if trace_tmp is not None:
+                    events, counters = obs.merge_shards(trace_tmp.name)
+                    tracer.adopt(events)
+                    for key, n in counters.items():
+                        tracer.counters[key] = tracer.counters.get(key, 0) + n
+                    trace_tmp.cleanup()
+            result = BatchResult.from_report(
+                report, pairs=[(job.dfg, job.cgra) for job in batch],
+                max_register_pressure=opts.max_register_pressure,
             )
-        finally:
-            if trace_tmp is not None:
-                events, counters = obs.merge_shards(trace_tmp.name)
-                tracer.adopt(events)
-                for key, n in counters.items():
-                    tracer.counters[key] = tracer.counters.get(key, 0) + n
-                trace_tmp.cleanup()
-        result = BatchResult.from_report(
-            report, pairs=[(job.dfg, job.cgra) for job in batch],
-            max_register_pressure=opts.max_register_pressure,
-        )
-        if opts.exact_check:
-            # certification is a caller-side post-pass (sequential, in
-            # process): worker rows stay lean and the sweep sees the exact
-            # reconstructed mapping every row was re-validated with
-            for job, row in zip(batch, result.results):
-                self._certify(job.dfg, row, opts)
-        result.wall_s = _time.perf_counter() - t0
-        return result
+            if opts.exact_check:
+                # certification is a caller-side post-pass (sequential, in
+                # process): worker rows stay lean and the sweep sees the
+                # exact reconstructed mapping every row was re-validated with
+                for job, row in zip(batch, result.results):
+                    self._certify(job.dfg, row, opts)
+            result.wall_s = _time.perf_counter() - t0
+            return result
 
     def compile_racing(
         self,

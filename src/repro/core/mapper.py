@@ -734,7 +734,7 @@ def _map_dfg_impl(
         sol: TimeSolution, w: _Window, rnd: int,
         node_budget: int, restarts: int, salt: int = 0,
     ) -> Mapping | None:
-        if not obs.enabled():
+        if not obs.recording():
             return _try_space(sol, w, rnd, node_budget, restarts, salt)
         n0, r0 = stats.space_nodes_visited, stats.space_restarts
         with obs.span("space.probe", ii=w.ii, slack=w.slack, round=rnd,

@@ -427,7 +427,7 @@ class AnnealSpaceBackend:
             # tuning"): purely observational — counters and obs events only,
             # never an rng draw, so traced and untraced runs take the
             # identical search path
-            traced = obs.enabled()
+            traced = obs.recording()
             accepts = proposals = 0
 
             def emit_restart(found: bool) -> None:

@@ -86,7 +86,7 @@ def find_monomorphism(
     # weights 1,1,2,4,...  (the last restart gets ~half the total budget)
     weights = [1] + [1 << min(r, 30) for r in range(n_restarts - 1)]
     total_w = sum(weights)
-    traced = obs.enabled()
+    traced = obs.recording()
     for r in range(n_restarts):
         remaining = budget - (_time.perf_counter() - start)
         if remaining <= 0:

@@ -245,4 +245,5 @@ def cgra_sim_pallas(
         scratch_shapes=[pltpu.VMEM((ring, pes, bt), jnp.float32)],
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem),
         interpret=interpret,
+        name="cgra_sim",
     )(route_a, route_b, op_sel, imm, inj, active)

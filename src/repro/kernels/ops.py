@@ -7,6 +7,11 @@ crossbar and opcode decoders become MXU/VPU-friendly tensors (DESIGN.md §3).
 ``cgra_run`` executes a compiled program over batched input streams and
 returns per-store-node outputs, via the Pallas kernel. It runs compiled for
 the TPU by default; CPU callers (the tests) pass ``interpret=True``.
+
+Both record ``repro.obs`` spans, which also land in a JAX profiler trace
+while one is collecting: ``lower`` around ``compile_program``, and
+``cgra_run`` with one child per step of a call (``cgra_run.stage``,
+``.to_device``, ``.launch``, ``.wait``, ``.to_host``, ``.extract``).
 """
 
 from __future__ import annotations
@@ -19,6 +24,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core.dfg import DFG
 from repro.core.mapper import Mapping
 from repro.core.simulate import OPCODES, _operands
@@ -68,6 +74,15 @@ def enable_compile_cache() -> str:
 
 
 def compile_program(mapping: Mapping) -> CGRAProgram:
+    """Lower a mapping to the kernel's tables, inside an ``obs`` span ``lower``."""
+    with obs.span("lower", kernel=mapping.dfg.name, ii=mapping.ii,
+                  pes=mapping.cgra.num_pes) as sp:
+        program = _lower(mapping)
+        sp.set(ring=program.ring)
+    return program
+
+
+def _lower(mapping: Mapping) -> CGRAProgram:
     dfg, cgra, ii = mapping.dfg, mapping.cgra, mapping.ii
     pes = cgra.num_pes
     labels, t_abs, placement = mapping.labels, mapping.t_abs, mapping.placement
@@ -137,6 +152,28 @@ def build_injection(
     return inj, active
 
 
+def kernel_operands(
+    program: CGRAProgram, inj: np.ndarray | jax.Array, active: np.ndarray | jax.Array
+) -> tuple:
+    """The kernel's six operands in its order: the four program tables, the
+    injections ``[C, pes, B]`` and the firing mask as ``[C, 1, pes]``."""
+    C, pes, _ = inj.shape
+    return (program.route_a, program.route_b, program.op_sel,
+            program.imm.reshape(program.ii, 1, pes), inj, active.reshape(C, 1, pes))
+
+
+def _dispatch(program: CGRAProgram, operands, batch_tile: int, interpret: bool) -> jax.Array:
+    C, _, batch = operands[4].shape
+    return cgra_sim_pallas(
+        *operands,
+        ii=program.ii,
+        ring=program.ring,
+        num_cycles=C,
+        batch_tile=min(batch_tile, batch),
+        interpret=interpret,
+    )
+
+
 def cgra_launch(
     program: CGRAProgram,
     inj: np.ndarray | jax.Array,      # [C, pes, B] f32 (build_injection)
@@ -145,21 +182,10 @@ def cgra_launch(
     batch_tile: int = 128,
     interpret: bool = False,
 ) -> jax.Array:
-    """Launch the kernel on staged injections; returns the device trace [C, pes, B]."""
-    C, pes, batch = inj.shape
-    return cgra_sim_pallas(
-        jnp.asarray(program.route_a),
-        jnp.asarray(program.route_b),
-        jnp.asarray(program.op_sel),
-        jnp.asarray(program.imm.reshape(program.ii, 1, pes)),
-        jnp.asarray(inj),
-        jnp.asarray(active.reshape(C, 1, pes)),
-        ii=program.ii,
-        ring=program.ring,
-        num_cycles=C,
-        batch_tile=min(batch_tile, batch),
-        interpret=interpret,
-    )
+    """Launch the kernel on staged injections; returns the device trace
+    [C, pes, B] without waiting for it."""
+    operands = tuple(jnp.asarray(x) for x in kernel_operands(program, inj, active))
+    return _dispatch(program, operands, batch_tile, interpret)
 
 
 def cgra_run(
@@ -174,15 +200,51 @@ def cgra_run(
 
     Compiled for the TPU unless ``interpret=True``; on a CPU backend the
     compiled path raises rather than falling back to the interpreter.
+
+    A call runs in explicit steps, each in its own ``obs`` span under
+    ``cgra_run`` (``kernel``, ``pes``, ``streams``, ``iters``, ``cycles``),
+    traced or not:
+
+    * ``cgra_run.stage``: ``build_injection`` on the host (``bytes``: inj
+      and active);
+    * ``cgra_run.to_device``: the program tables, inj and active to the
+      device, waited for together (``table_bytes``, ``inj_bytes``: inj and
+      active);
+    * ``cgra_run.launch``: the kernel's dispatch (and its compile, if any);
+    * ``cgra_run.wait``: until the kernel's trace is ready;
+    * ``cgra_run.to_host``: the trace back to the host (``bytes``);
+    * ``cgra_run.extract``: the store outputs gathered from the trace
+      (``bytes``).
     """
-    inj, active = build_injection(program, inputs, num_iters)
-    trace = np.asarray(
-        cgra_launch(program, inj, active, batch_tile=batch_tile, interpret=interpret)
-    )
     m = program.mapping
-    outs: dict[int, np.ndarray] = {}
-    for v in m.dfg.nodes:
-        if m.dfg.ops[v] == "store":
-            cyc = m.t_abs[v] + np.arange(num_iters) * m.ii
-            outs[v] = trace[cyc, m.placement[v], :]
+    with obs.span("cgra_run", kernel=m.dfg.name, pes=program.num_pes,
+                  iters=num_iters) as run:
+        with obs.span("cgra_run.stage") as sp:
+            inj, active = build_injection(program, inputs, num_iters)
+            staged_bytes = inj.nbytes + active.nbytes
+            sp.set(bytes=staged_bytes)
+        run.set(streams=inj.shape[2], cycles=inj.shape[0])
+
+        operands = kernel_operands(program, inj, active)
+        with obs.span("cgra_run.to_device",
+                      table_bytes=sum(x.nbytes for x in operands[:4]),
+                      inj_bytes=staged_bytes):
+            operands = jax.block_until_ready(jax.device_put(operands))
+        with obs.span("cgra_run.launch"):
+            out = _dispatch(program, operands, batch_tile, interpret)
+        # the host's staging buffers are on the device now: free them (GBs
+        # at 20x20) while the kernel runs, inside the call's span
+        del operands, inj, active
+        with obs.span("cgra_run.wait"):
+            out.block_until_ready()
+        with obs.span("cgra_run.to_host", bytes=out.nbytes):
+            trace = np.asarray(out)
+
+        with obs.span("cgra_run.extract") as sp:
+            outs: dict[int, np.ndarray] = {}
+            for v in m.dfg.nodes:
+                if m.dfg.ops[v] == "store":
+                    cyc = m.t_abs[v] + np.arange(num_iters) * m.ii
+                    outs[v] = trace[cyc, m.placement[v], :]
+            sp.set(bytes=sum(o.nbytes for o in outs.values()))
     return outs, trace

@@ -13,7 +13,8 @@ Design contract (the "overhead contract"):
 
 * **Disabled is the default and costs almost nothing.** The module-level
   ``_ACTIVE`` tracer is ``None`` unless a CLI or test installs one;
-  ``span()`` / ``event()`` / ``incr()`` check it first and return a shared
+  ``span()`` / ``event()`` / ``incr()`` check it first (and, for spans and
+  events, whether a JAX profiler trace is collecting) and return a shared
   ``_NULL_SPAN`` singleton without allocating. Instrumentation sites can
   therefore stay inline in hot loops (mapper rounds, solver probes).
 * **Stdlib only, imports nothing from ``repro``.** Like
@@ -23,6 +24,12 @@ Design contract (the "overhead contract"):
   (``time.time()`` at tracer start + ``perf_counter`` deltas), so span
   shards written by service worker processes merge onto the parent's
   timeline with pid/tid attribution intact.
+* **The device's clock too.** While a JAX profiler trace is collecting,
+  every span also enters a ``jax.profiler.TraceAnnotation`` with the same
+  name and attributes (``set`` forwards to its metadata; an event is a
+  zero-length annotation), so it lands in the profiler's host timeline
+  beside the device's ops. jax is looked up in ``sys.modules``, never
+  imported here: a process that has not imported it has no such trace.
 
 Serialization is the Chrome trace-event JSON flavor (``"X"`` complete
 events, ``"i"`` instants, ``"M"`` metadata) that Perfetto / ``chrome://
@@ -34,6 +41,7 @@ from __future__ import annotations
 
 import json
 import os
+import sys
 import threading
 import time
 from contextlib import contextmanager
@@ -48,6 +56,7 @@ __all__ = [
     "incr",
     "install_tracer",
     "merge_shards",
+    "recording",
     "session",
     "span",
     "tracing",
@@ -56,6 +65,24 @@ __all__ = [
 # The process-global active tracer. ``None`` means tracing is disabled and
 # every obs call short-circuits through the no-op fast path below.
 _ACTIVE: "Tracer | None" = None
+
+# ``jax.profiler.TraceAnnotation`` and its ``is_enabled`` once jax is
+# imported (see _annotation).
+_ANNOTATION = None
+_PROFILING = None
+
+
+def _annotation():
+    """The profiler's annotation class while a JAX profiler trace is
+    collecting, else None. The class is cached once jax is imported; until
+    then each call is one lookup in ``sys.modules``."""
+    global _ANNOTATION, _PROFILING
+    if _PROFILING is None:
+        cls = getattr(sys.modules.get("jax.profiler"), "TraceAnnotation", None)
+        if cls is None:
+            return None
+        _ANNOTATION, _PROFILING = cls, cls.is_enabled
+    return _ANNOTATION if _PROFILING() else None
 
 
 def env_enabled() -> bool:
@@ -68,6 +95,13 @@ def env_enabled() -> bool:
 def enabled() -> bool:
     """True when a tracer is currently installed."""
     return _ACTIVE is not None
+
+
+def recording() -> bool:
+    """True when a span would be recorded anywhere: a tracer is installed or
+    a JAX profiler trace is collecting. Sites guard costly attribute work
+    with it."""
+    return _ACTIVE is not None or _annotation() is not None
 
 
 def get_tracer() -> "Tracer | None":
@@ -93,32 +127,40 @@ _NULL_SPAN = _NullSpan()
 
 
 class _Span:
-    """A live span: records an ``"X"`` complete event on exit."""
+    """A live span: records an ``"X"`` complete event on the tracer (if one
+    is installed) and a profiler annotation (if a trace is collecting)."""
 
-    __slots__ = ("_tracer", "name", "args", "_t0", "_ts")
+    __slots__ = ("_tracer", "_annotation", "name", "args", "_t0", "_ts")
 
-    def __init__(self, tracer: "Tracer", name: str, args: dict):
+    def __init__(self, tracer: "Tracer | None", annotation, name: str, args: dict):
         self._tracer = tracer
+        self._annotation = annotation(name, **args) if annotation is not None else None
         self.name = name
         self.args = args
         self._t0 = 0.0
         self._ts = 0.0
 
     def __enter__(self):
-        self._t0 = time.perf_counter()
-        self._ts = self._tracer._now_us()
-        self._tracer._push(self.name)
+        if self._annotation is not None:
+            self._annotation.__enter__()
+        if self._tracer is not None:
+            self._t0 = time.perf_counter()
+            self._ts = self._tracer._now_us()
         return self
 
     def __exit__(self, *exc):
-        dur_us = (time.perf_counter() - self._t0) * 1e6
-        self._tracer._pop()
-        self._tracer._emit_complete(self.name, self._ts, dur_us, self.args)
+        if self._tracer is not None:
+            dur_us = (time.perf_counter() - self._t0) * 1e6
+            self._tracer._emit_complete(self.name, self._ts, dur_us, self.args)
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc)
         return False
 
     def set(self, **attrs):
         """Attach/override attributes after the span started."""
         self.args.update(attrs)
+        if self._annotation is not None:
+            self._annotation.set_metadata(**attrs)
         return self
 
 
@@ -139,29 +181,10 @@ class Tracer:
         self._lock = threading.Lock()
         self.events: list[dict] = []
         self.counters: dict[str, int] = {}
-        self._stacks: "threading.local" = threading.local()
 
     # -- time ------------------------------------------------------------
     def _now_us(self) -> float:
         return self._epoch_us + (time.perf_counter() - self._anchor) * 1e6
-
-    # -- span-stack bookkeeping (per thread, for depth-aware reports) -----
-    def _stack(self) -> list:
-        st = getattr(self._stacks, "stack", None)
-        if st is None:
-            st = self._stacks.stack = []
-        return st
-
-    def _push(self, name: str) -> None:
-        self._stack().append(name)
-
-    def _pop(self) -> None:
-        st = self._stack()
-        if st:
-            st.pop()
-
-    def depth(self) -> int:
-        return len(self._stack())
 
     # -- event emission ---------------------------------------------------
     def _emit_complete(self, name, ts_us, dur_us, args) -> None:
@@ -218,6 +241,13 @@ class Tracer:
         """Write one drained segment as a standalone Chrome trace document
         (same schema as :meth:`write`, so ``tools/trace_report.py`` loads
         rotated daemon segments and one-shot CLI traces identically)."""
+        with open(path, "w") as f:
+            json.dump(self._document(events), f)
+
+    # -- serialization ----------------------------------------------------
+    def _document(self, events: list[dict]) -> dict:
+        """The Chrome trace document of ``events``: one ``process_name``
+        record per pid, then the events, then the counters."""
         pids = sorted({e["pid"] for e in events} | {self.pid})
         meta = [{
             "name": "process_name", "ph": "M", "pid": pid, "tid": 0,
@@ -228,32 +258,13 @@ class Tracer:
         with self._lock:
             if self.counters:
                 doc["otherData"] = {"counters": dict(self.counters)}
-        with open(path, "w") as f:
-            json.dump(doc, f)
-
-    # -- serialization ----------------------------------------------------
-    def metadata_events(self) -> list[dict]:
-        pids = sorted({e["pid"] for e in self.events} | {self.pid})
-        meta = []
-        for pid in pids:
-            label = self.process_name if pid == self.pid else f"worker-{pid}"
-            meta.append({
-                "name": "process_name", "ph": "M", "pid": pid, "tid": 0,
-                "args": {"name": label},
-            })
-        return meta
+        return doc
 
     def to_chrome(self) -> dict:
         """The Perfetto-loadable Chrome trace-event JSON document."""
         with self._lock:
             events = list(self.events)
-        doc = {
-            "traceEvents": self.metadata_events() + events,
-            "displayTimeUnit": "ms",
-        }
-        if self.counters:
-            doc["otherData"] = {"counters": dict(self.counters)}
-        return doc
+        return self._document(events)
 
     def write(self, path: str) -> None:
         with open(path, "w") as f:
@@ -273,18 +284,28 @@ class Tracer:
 # -- module-level API (the only names instrumentation sites use) ----------
 
 def span(name: str, **attrs):
-    """Context manager timing a named span; no-op when tracing is disabled."""
+    """Context manager timing a named span; no-op when nothing records."""
     t = _ACTIVE
-    if t is None:
+    # _annotation() inlined: this is the path every disabled span takes
+    profiling = _PROFILING
+    if profiling is None:
+        cls = _annotation()
+    else:
+        cls = _ANNOTATION if profiling() else None
+    if cls is None and t is None:
         return _NULL_SPAN
-    return _Span(t, name, attrs)
+    return _Span(t, cls, name, attrs)
 
 
 def event(name: str, **attrs) -> None:
-    """Record a zero-duration instant event; no-op when disabled."""
+    """Record a zero-duration instant event; no-op when nothing records."""
     t = _ACTIVE
     if t is not None:
         t.emit_instant(name, attrs)
+    annotation = _annotation()
+    if annotation is not None:
+        with annotation(name, **attrs):
+            pass
 
 
 def incr(name: str, n: int = 1) -> None:

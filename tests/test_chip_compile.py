@@ -9,6 +9,8 @@ The topology is described inside a fixture, never at import: only one
 process may hold the TPU library, and the test workers all import this file.
 """
 
+import re
+
 import pytest
 
 import jax
@@ -82,3 +84,16 @@ def test_cgra_sim_compiles_for_v5e(fft_program, kernel_shapes):
         batch_tile=BATCH_TILE, interpret=False,
     ).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_cgra_sim_custom_call_is_named_by_the_kernel(fft_program, kernel_shapes):
+    """The kernel's HLO instruction, the name a device trace shows, comes
+    from the ``pallas_call``'s own ``name``, not from the jitted wrapper."""
+    p = fft_program
+    text = cgra_sim_pallas.lower(
+        *kernel_shapes, ii=p.ii, ring=p.ring, num_cycles=num_cycles(p, ITERS),
+        batch_tile=BATCH_TILE, interpret=False,
+    ).compile().as_text()
+    (call,) = [line for line in text.splitlines() if "custom-call(" in line]
+    assert re.search(r"%cgra_sim(\.\d+)? = \S+ custom-call\(", call), call[:200]
+    assert 'op_name="jit(cgra_sim_pallas)/cgra_sim/pallas_call"' in call

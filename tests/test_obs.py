@@ -5,8 +5,9 @@ Covers the whole observability contract: the disabled-mode no-op fast path
 ``deterministic-ci`` profile, cross-process shard merging from a 2-worker
 ``compile_many``, Chrome/Perfetto trace-event schema validation via
 ``tools/trace_report.py``, the ``exact_s`` phase-accounting fix, the
-two-layer cache counters, and the metrics-block parity between the
-in-process, batch, and pooled paths.
+two-layer cache counters, the metrics-block parity between the
+in-process, batch, and pooled paths, and spans reaching a JAX profiler
+trace while one collects.
 """
 
 import importlib.util
@@ -318,3 +319,58 @@ def test_session_env_gate(monkeypatch, tmp_path):
     with obs.session() as t:
         assert t is not None and obs.enabled()
     assert not obs.enabled()
+
+
+# ------------------------------------------------------ profiler sink
+# (last in the file: these import jax, whose threads make the pool tests'
+# fork unsafe)
+
+def test_span_lands_in_profiler_trace(profiled):
+    """Inside a JAX profiler trace, with no tracer installed, a span and its
+    post-hoc attributes land on the profiler's host timeline, and an event
+    lands as a zero-length annotation."""
+    assert not obs.enabled()
+    with profiled() as events:
+        assert obs.recording() and not obs.enabled()
+        with obs.span("obs.outer", ii=4, kernel="fft") as sp:
+            with obs.span("obs.inner"):
+                pass
+            sp.set(found=True, nodes=12)
+        obs.event("obs.instant", hit=1)
+    assert not obs.recording()
+    got = {name: (start, end, stats) for name, start, end, stats in events
+           if name.startswith("obs.")}
+    assert set(got) == {"obs.outer", "obs.inner", "obs.instant"}
+    outer, inner = got["obs.outer"], got["obs.inner"]
+    assert outer[2] == {"ii": 4, "kernel": "fft", "found": 1, "nodes": 12}
+    assert outer[0] <= inner[0] <= inner[1] <= outer[1]
+    start, end, stats = got["obs.instant"]
+    assert stats == {"hit": 1} and end - start < 1e6
+
+
+def test_no_profiler_no_tracer_is_null_span(profiled):
+    """Once a profiler trace has stopped (jax imported, no tracer), span()
+    is back on the shared no-op singleton."""
+    with profiled():
+        assert obs.span("x") is not obs._NULL_SPAN
+    assert obs.span("x", ii=1) is obs._NULL_SPAN
+    assert not obs.recording()
+
+
+def test_profiler_leaves_chrome_json_unchanged(profiled):
+    """A tracer records the same events, with the same attributes, whether
+    or not a profiler trace collects at the same time."""
+    dfg = running_example()
+
+    def signature():
+        _, tracer = _traced_compile(dfg)
+        return [(e["name"], e["ph"], sorted(e["args"].items()))
+                for e in tracer.events]
+
+    plain = signature()
+    with profiled() as events:
+        both = signature()
+    assert plain == both
+    # the same spans reached the profiler, space probes included
+    names = {name for name, *_ in events}
+    assert {"compile", "time.probe", "space.probe"} <= names
