@@ -43,6 +43,7 @@ from repro.kernels.ops import (  # noqa: E402
     cgra_run,
     compile_program,
     enable_compile_cache,
+    stage_injection,
 )
 from repro.kernels.ref import cgra_sim_reference  # noqa: E402
 
@@ -129,7 +130,8 @@ def run_phase(name, dfg, fabric, compile_clock, rng) -> dict:
 
     tables = sum(getattr(prog, f).nbytes for f in ("route_a", "route_b", "op_sel", "imm"))
     cycles = trace.shape[0]
-    staged = trace.nbytes + cycles * prog.num_pes * 4   # inj [C, pes, B], active [C, pes]
+    # what cgra_run sends: the input rows, their cycles and PEs, and active
+    staged = stage_injection(prog, inputs, ITERS).nbytes
     return {
         "kernel": name,
         "fabric": f"{fabric[0]}x{fabric[1]}",

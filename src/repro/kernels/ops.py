@@ -6,7 +6,12 @@ crossbar and opcode decoders become MXU/VPU-friendly tensors (DESIGN.md §3).
 
 ``cgra_run`` executes a compiled program over batched input streams and
 returns per-store-node outputs, via the Pallas kernel. It runs compiled for
-the TPU by default; CPU callers (the tests) pass ``interpret=True``.
+the TPU by default; CPU callers (the tests) pass ``interpret=True``. The
+kernel reads a dense injection plane ``[C, pes, B]`` that is zero but for one
+row per input node and iteration. ``cgra_run`` ships only those rows, with
+each row's cycle and PE (``stage_injection``), and ``place_injection`` builds
+the plane from them on the device. ``build_injection`` builds the same plane
+on the host: the reference the tests and ``ref.py`` hold it to.
 
 Both record ``repro.obs`` spans, which also land in a JAX profiler trace
 while one is collecting: ``lower`` around ``compile_program``, and
@@ -16,9 +21,11 @@ while one is collecting: ``lower`` around ``compile_program``, and
 
 from __future__ import annotations
 
+import functools
 import os
 from dataclasses import dataclass
 from pathlib import Path
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -152,14 +159,75 @@ def build_injection(
     return inj, active
 
 
+class StagedInjection(NamedTuple):
+    """What ``cgra_run`` stages on the host in place of ``build_injection``'s
+    planes: the rows ``place_injection`` writes into the injection plane on
+    the device, and the firing mask, which is small."""
+
+    rows: tuple[np.ndarray, ...]   # per input node, [iters, B] f32: its values
+    cycle: np.ndarray              # [n_in * iters] int32: each row's cycle
+    pe: np.ndarray                 # [n_in * iters] int32: each row's PE
+    active: np.ndarray             # [C, pes] f32, as build_injection's
+    batch: int
+
+    @property
+    def nbytes(self) -> int:
+        return (sum(r.nbytes for r in self.rows) + self.cycle.nbytes
+                + self.pe.nbytes + self.active.nbytes)
+
+
+def stage_injection(
+    program: CGRAProgram, inputs: dict[int, np.ndarray], num_iters: int
+) -> StagedInjection:
+    """The input values as rows, node by node in graph order, each row's
+    cycle ``t_abs[v] + it * ii`` and PE ``placement[v]``, and the firing
+    mask. The rows are ``inputs[v]`` themselves where they are f32 already."""
+    m = program.mapping
+    its = np.arange(num_iters)
+    nodes = [v for v in m.dfg.nodes if m.dfg.ops[v] == "input"]
+    rows = tuple(np.asarray(inputs[v][:num_iters], np.float32) for v in nodes)
+    cycle = np.array([m.t_abs[v] + it * m.ii for v in nodes for it in its], np.int32)
+    pe = np.repeat(np.array([m.placement[v] for v in nodes], np.int32), num_iters)
+    active = np.zeros((num_cycles(program, num_iters), program.num_pes), np.float32)
+    active[np.add.outer(m.t_abs, its * m.ii), np.array(m.placement)[:, None]] = 1.0
+    batch = next(iter(inputs.values())).shape[1] if inputs else 1
+    return StagedInjection(rows, cycle, pe, active, batch)
+
+
+@functools.partial(jax.jit, static_argnames=("num_cycles", "pes", "batch"))
+def place_injection(
+    rows: tuple[jax.Array, ...],   # per input node, [iters, B] f32
+    cycle: jax.Array,              # [n_in * iters] int32
+    pe: jax.Array,                 # [n_in * iters] int32
+    *,
+    num_cycles: int,
+    pes: int,
+    batch: int,
+) -> jax.Array:
+    """The injection plane ``[C, pes, B]`` built on the device: zeros, and
+    each staged row at its (cycle, PE). A mapping holds one node per PE and
+    kernel step, so no two rows share a (cycle, PE)."""
+    plane = jnp.zeros((num_cycles, pes, batch), jnp.float32)
+    if not rows:
+        return plane
+    return plane.at[cycle, pe].set(jnp.concatenate(rows), unique_indices=True,
+                                   mode="promise_in_bounds")
+
+
+def program_tables(program: CGRAProgram) -> tuple[np.ndarray, ...]:
+    """The kernel's first four operands: the routing, opcode and immediate
+    tables, the immediates as ``[II, 1, pes]``."""
+    return (program.route_a, program.route_b, program.op_sel,
+            program.imm.reshape(program.ii, 1, program.num_pes))
+
+
 def kernel_operands(
     program: CGRAProgram, inj: np.ndarray | jax.Array, active: np.ndarray | jax.Array
 ) -> tuple:
     """The kernel's six operands in its order: the four program tables, the
     injections ``[C, pes, B]`` and the firing mask as ``[C, 1, pes]``."""
     C, pes, _ = inj.shape
-    return (program.route_a, program.route_b, program.op_sel,
-            program.imm.reshape(program.ii, 1, pes), inj, active.reshape(C, 1, pes))
+    return (*program_tables(program), inj, active.reshape(C, 1, pes))
 
 
 def _dispatch(program: CGRAProgram, operands, batch_tile: int, interpret: bool) -> jax.Array:
@@ -205,11 +273,12 @@ def cgra_run(
     ``cgra_run`` (``kernel``, ``pes``, ``streams``, ``iters``, ``cycles``),
     traced or not:
 
-    * ``cgra_run.stage``: ``build_injection`` on the host (``bytes``: inj
-      and active);
-    * ``cgra_run.to_device``: the program tables, inj and active to the
-      device, waited for together (``table_bytes``, ``inj_bytes``: inj and
-      active);
+    * ``cgra_run.stage``: ``stage_injection`` on the host (``bytes``: the
+      input rows, their cycles and PEs, and active);
+    * ``cgra_run.to_device``: the program tables, the staged rows and active
+      to the device, and ``place_injection`` building inj there from the
+      rows, waited for together (``table_bytes``; ``inj_bytes``: the staged
+      bytes sent; ``plane_bytes``: inj and active as the kernel reads them);
     * ``cgra_run.launch``: the kernel's dispatch (and its compile, if any);
     * ``cgra_run.wait``: until the kernel's trace is ready;
     * ``cgra_run.to_host``: the trace back to the host (``bytes``);
@@ -220,21 +289,24 @@ def cgra_run(
     with obs.span("cgra_run", kernel=m.dfg.name, pes=program.num_pes,
                   iters=num_iters) as run:
         with obs.span("cgra_run.stage") as sp:
-            inj, active = build_injection(program, inputs, num_iters)
-            staged_bytes = inj.nbytes + active.nbytes
-            sp.set(bytes=staged_bytes)
-        run.set(streams=inj.shape[2], cycles=inj.shape[0])
+            staged = stage_injection(program, inputs, num_iters)
+            sp.set(bytes=staged.nbytes)
+        C, pes = staged.active.shape
+        run.set(streams=staged.batch, cycles=C)
 
-        operands = kernel_operands(program, inj, active)
+        tables = program_tables(program)
         with obs.span("cgra_run.to_device",
-                      table_bytes=sum(x.nbytes for x in operands[:4]),
-                      inj_bytes=staged_bytes):
-            operands = jax.block_until_ready(jax.device_put(operands))
+                      table_bytes=sum(x.nbytes for x in tables),
+                      inj_bytes=staged.nbytes,
+                      plane_bytes=4 * C * pes * (staged.batch + 1)):
+            tables, rows, cycle, pe, active = jax.device_put(
+                (tables, staged.rows, staged.cycle, staged.pe,
+                 staged.active.reshape(C, 1, pes)))
+            inj = place_injection(rows, cycle, pe, num_cycles=C, pes=pes,
+                                  batch=staged.batch)
+            operands = jax.block_until_ready((*tables, inj, active))
         with obs.span("cgra_run.launch"):
             out = _dispatch(program, operands, batch_tile, interpret)
-        # the host's staging buffers are on the device now: free them (GBs
-        # at 20x20) while the kernel runs, inside the call's span
-        del operands, inj, active
         with obs.span("cgra_run.wait"):
             out.block_until_ready()
         with obs.span("cgra_run.to_host", bytes=out.nbytes):

@@ -2,7 +2,8 @@
 profiler trace sees them (CPU, kernel in Pallas interpret mode).
 
 A call splits into six steps, each a span nested under ``cgra_run``, in
-order; the byte attributes equal the arrays' sizes, and a traced call
+order; the byte attributes equal the arrays' sizes (the host stages and
+sends the input rows, the device builds the planes), and a traced call
 returns what an untraced one does.
 """
 
@@ -17,7 +18,7 @@ from repro.kernels.ops import (
     build_injection,
     cgra_run,
     compile_program,
-    kernel_operands,
+    program_tables,
 )
 
 STEPS = ["cgra_run.stage", "cgra_run.to_device", "cgra_run.launch",
@@ -54,13 +55,17 @@ def test_cgra_run_spans_nest_in_order(gsm, profiled):
     assert stats == {"kernel": "gsm", "pes": 16, "iters": ITERS,
                      "streams": STREAMS, "cycles": trace.shape[0]}
 
+    # what is staged and sent is the input rows, their cycles and PEs and
+    # active; the planes the kernel reads are built on the device
     inj, active = build_injection(program, inputs, ITERS)
-    tables = kernel_operands(program, inj, active)[:4]
+    n_rows = len(inputs) * ITERS
+    staged = 4 * n_rows * STREAMS + 2 * 4 * n_rows + active.nbytes
     got = {name: s for name, _, _, s in steps}
-    assert got["cgra_run.stage"] == {"bytes": inj.nbytes + active.nbytes}
+    assert got["cgra_run.stage"] == {"bytes": staged}
     assert got["cgra_run.to_device"] == {
-        "table_bytes": sum(t.nbytes for t in tables),
-        "inj_bytes": inj.nbytes + active.nbytes}
+        "table_bytes": sum(t.nbytes for t in program_tables(program)),
+        "inj_bytes": staged,
+        "plane_bytes": inj.nbytes + active.nbytes}
     assert got["cgra_run.to_host"] == {"bytes": trace.nbytes}
     assert got["cgra_run.extract"] == {"bytes": sum(o.nbytes for o in outs.values())}
     assert got["cgra_run.launch"] == got["cgra_run.wait"] == {}

@@ -20,7 +20,7 @@ from repro.api import Compiler, resolve_options
 from repro.core import CGRA
 from repro.core.benchsuite import load_suite
 from repro.kernels.cgra_sim import NOPS, cgra_sim_pallas
-from repro.kernels.ops import compile_program, num_cycles
+from repro.kernels.ops import compile_program, num_cycles, place_injection
 
 BATCH, BATCH_TILE, ITERS = 256, 128, 4
 
@@ -97,3 +97,22 @@ def test_cgra_sim_custom_call_is_named_by_the_kernel(fft_program, kernel_shapes)
     (call,) = [line for line in text.splitlines() if "custom-call(" in line]
     assert re.search(r"%cgra_sim(\.\d+)? = \S+ custom-call\(", call), call[:200]
     assert 'op_name="jit(cgra_sim_pallas)/cgra_sim/pallas_call"' in call
+
+
+def test_injection_plane_builds_for_v5e(fft_program, one_chip):
+    """``place_injection`` at the farm's sizes (64 iterations, 819 200
+    PE-streams a call) compiles for the chip and holds no second plane."""
+    p = fft_program
+    m = p.mapping
+    iters, batch = 64, 819_200 // p.num_pes
+    C = num_cycles(p, iters)
+    n_in = sum(1 for v in m.dfg.nodes if m.dfg.ops[v] == "input")
+    rows = tuple(jax.ShapeDtypeStruct((iters, batch), jnp.float32, sharding=one_chip)
+                 for _ in range(n_in))
+    index = jax.ShapeDtypeStruct((n_in * iters,), jnp.int32, sharding=one_chip)
+    compiled = place_injection.lower(
+        rows, index, index, num_cycles=C, pes=p.num_pes, batch=batch).compile()
+    mem = compiled.memory_analysis()
+    plane = 4 * C * p.num_pes * batch
+    assert mem.output_size_in_bytes == plane
+    assert mem.temp_size_in_bytes < plane // 10
