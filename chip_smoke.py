@@ -55,6 +55,8 @@ KERNELS = ("fft", "crc32", "sha2", "gsm", "particlefilter")
 FABRICS = ((4, 4), (20, 20))
 STREAMS, ITERS, SEED = 2048, 64, 0
 SAMPLED_STREAMS = 4
+# the program's arrays the kernel reads: route pairs and codes, opcodes, imms
+PROGRAM_TABLES = ("route_pairs", "route", "op_sel", "imm")
 BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
 
 
@@ -107,7 +109,7 @@ def run_phase(name, dfg, fabric, compile_clock, rng) -> dict:
     inj, active = build_injection(prog, inputs, ITERS)
     on_device = dataclasses.replace(
         prog, **{f: jax.device_put(getattr(prog, f))
-                 for f in ("route_a", "route_b", "op_sel", "imm")}
+                 for f in PROGRAM_TABLES}
     )
     inj, active = jax.device_put(inj), jax.device_put(active)
     jax.block_until_ready(cgra_launch(on_device, inj, active))
@@ -128,7 +130,7 @@ def run_phase(name, dfg, fabric, compile_clock, rng) -> dict:
                 err_msg=f"{name} {fabric}: store {v}, stream {lane} vs interpret_dfg",
             )
 
-    tables = sum(getattr(prog, f).nbytes for f in ("route_a", "route_b", "op_sel", "imm"))
+    tables = sum(getattr(prog, f).nbytes for f in PROGRAM_TABLES)
     cycles = trace.shape[0]
     # what cgra_run sends: the input rows, their cycles and PEs, and active
     staged = stage_injection(prog, inputs, ITERS).nbytes

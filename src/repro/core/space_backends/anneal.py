@@ -163,8 +163,34 @@ class AnnealSpaceBackend:
         stats: SpaceStats | None = None,
         should_stop=None,
     ) -> SpaceSolution | None:
-        b = budget if budget is not None else SpaceBudget()
+        """One placement attempt, inside an ``obs`` span ``space.anneal``
+        (``pes``; ``moves``, ``restarts``, ``kicks``: this call's SA moves,
+        restarts and deblocking kicks)."""
         stats = stats if stats is not None else SpaceStats()
+        moves, restarts, kicks = stats.nodes_visited, stats.restarts, stats.kicks
+        with obs.span("space.anneal", pes=cgra.num_pes) as sp:
+            sol = self._place(dfg, cgra, labels, ii, t_abs=t_abs,
+                              max_route_hops=max_route_hops, budget=budget,
+                              seed=seed, stats=stats, should_stop=should_stop)
+            sp.set(moves=stats.nodes_visited - moves,
+                   restarts=stats.restarts - restarts, kicks=stats.kicks - kicks)
+        return sol
+
+    def _place(
+        self,
+        dfg: DFG,
+        cgra: CGRA,
+        labels: list[int],
+        ii: int,
+        *,
+        t_abs: list[int] | None,
+        max_route_hops: int,
+        budget: SpaceBudget | None,
+        seed: int,
+        stats: SpaceStats,
+        should_stop,
+    ) -> SpaceSolution | None:
+        b = budget if budget is not None else SpaceBudget()
         n = dfg.num_nodes
         num_pes = cgra.num_pes
         rows, cols = cgra.rows, cgra.cols
@@ -583,6 +609,7 @@ class AnnealSpaceBackend:
                     if route_attempts > _MAX_ROUTE_ATTEMPTS:
                         break
                     # deblock: kick a few nodes loose and keep annealing warm
+                    stats.kicks += 1
                     for _ in range(max(2, n // 10)):
                         v = rng.randrange(n)
                         lv = labels[v]

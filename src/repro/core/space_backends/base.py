@@ -61,6 +61,7 @@ class SpaceStats:
     backtracks: int = 0
     restarts: int = 0
     route_failures: int = 0        # complete placements whose movs didn't fit
+    kicks: int = 0                 # annealing deblocks (a few nodes kicked loose)
 
 
 @dataclass(frozen=True)

@@ -1,8 +1,8 @@
 """Pallas TPU kernels.
 
 cgra_sim.py          batched execution of mapped CGRA programs (the paper's
-                     compute substrate as a TPU kernel: crossbar -> one-hot
-                     MXU matmuls, register files -> VMEM ring buffer)
+                     compute substrate as a TPU kernel: crossbar -> neighbour
+                     shifts and VPU selects, register files -> VMEM ring buffer)
 flash_attention.py   fused attention (causal/sliding-window/softcap/GQA) —
                      the TPU hot path behind the model zoo's blocked-attention
                      jnp fallback

@@ -1,8 +1,12 @@
 """Jit'd wrappers and program compilation for the Pallas kernels.
 
 ``compile_program`` lowers a space-time Mapping (core/mapper.py) into the
-dense one-hot tables the cgra_sim kernel consumes — the step where the CGRA's
-crossbar and opcode decoders become MXU/VPU-friendly tensors (DESIGN.md §3).
+tables the cgra_sim kernel consumes — the step where the CGRA's crossbar and
+opcode decoders become VPU-friendly integer codes and one-hot rows
+(DESIGN.md §17). Each operand read is a route pair: how many cycles ago its
+value was produced, and the PE offset of its producer. The program keeps
+its distinct pairs, and per kernel step and PE the index of each operand's
+pair, so it grows as II x pes, never as pes**2.
 
 ``cgra_run`` executes a compiled program over batched input streams and
 returns per-store-node outputs, via the Pallas kernel. It runs compiled for
@@ -43,16 +47,18 @@ assert list(KERNEL_OPS) == list(OPCODES), "kernel/oracle opcode tables diverged"
 
 @dataclass
 class CGRAProgram:
-    """Dense, device-ready encoding of one mapped loop kernel."""
+    """Device-ready encoding of one mapped loop kernel."""
 
     mapping: Mapping
     ii: int
     ring: int
     num_pes: int
-    # one-hot tables, per kernel step
-    route_a: np.ndarray    # [II, pes, ring*pes] f32
-    route_b: np.ndarray    # [II, pes, ring*pes] f32
-    op_sel: np.ndarray     # [II, pes, NOPS] f32
+    # the distinct operand reads: (delta, offset), the value produced delta
+    # cycles ago (1..ring) at PE pe + offset
+    route_pairs: np.ndarray  # [P, 2] int32
+    # tables, per kernel step
+    route: np.ndarray      # [II, pes, 2] int32: route pair of operand a, b (-1 = none)
+    op_sel: np.ndarray     # [II, pes, NOPS] f32 one-hot
     imm: np.ndarray        # [II, pes] f32
     # integer views (used by ref.py and the injection builder)
     op_id: np.ndarray      # [II, pes] int32 (-1 = idle)
@@ -60,9 +66,15 @@ class CGRAProgram:
     src_pe: np.ndarray     # [II, pes, 2] int32
     src_delta: np.ndarray  # [II, pes, 2] int32 (cycles since operand produced)
 
+    @property
+    def offsets(self) -> tuple[int, ...]:
+        """The distinct PE offsets (``src_pe - pe``) the program reads over."""
+        return tuple(sorted({int(d) for d in self.route_pairs[:, 1]}))
+
     def vmem_bytes(self, batch_tile: int) -> int:
         """VMEM one kernel grid step needs (the kernel's ``vmem_limit_bytes``)."""
-        return vmem_footprint(self.num_pes, self.ring, batch_tile)
+        return vmem_footprint(self.num_pes, self.ring, batch_tile,
+                              max(1, len(self.route_pairs)))
 
 
 def enable_compile_cache() -> str:
@@ -81,11 +93,14 @@ def enable_compile_cache() -> str:
 
 
 def compile_program(mapping: Mapping) -> CGRAProgram:
-    """Lower a mapping to the kernel's tables, inside an ``obs`` span ``lower``."""
+    """Lower a mapping to the kernel's tables, inside an ``obs`` span
+    ``lower`` (``kernel``, ``ii``, ``pes``; ``ring``, ``route_pairs``: the
+    number of distinct pairs, ``table_bytes``: the program tables' bytes)."""
     with obs.span("lower", kernel=mapping.dfg.name, ii=mapping.ii,
                   pes=mapping.cgra.num_pes) as sp:
         program = _lower(mapping)
-        sp.set(ring=program.ring)
+        sp.set(ring=program.ring, route_pairs=len(program.route_pairs),
+               table_bytes=sum(t.nbytes for t in program_tables(program)))
     return program
 
 
@@ -106,8 +121,10 @@ def _lower(mapping: Mapping) -> CGRAProgram:
             srcs[v].append(placement[e.src])
     ring = max((d for ds in deltas for d in ds), default=1)
 
-    route_a = np.zeros((ii, pes, ring * pes), np.float32)
-    route_b = np.zeros((ii, pes, ring * pes), np.float32)
+    pairs = sorted({(dl, sp - placement[v])
+                    for v in dfg.nodes for sp, dl in zip(srcs[v], deltas[v])})
+    pair_of = {pair: i for i, pair in enumerate(pairs)}
+    route = np.full((ii, pes, 2), -1, np.int32)
     op_sel = np.zeros((ii, pes, NOPS), np.float32)
     imm = np.zeros((ii, pes), np.float32)
     op_id = np.full((ii, pes), -1, np.int32)
@@ -123,15 +140,14 @@ def _lower(mapping: Mapping) -> CGRAProgram:
         node_at[k, pe] = v
         imm[k, pe] = dfg.imms[v]
         for slot, (sp, dl) in enumerate(zip(srcs[v], deltas[v])):
-            # ring slot dl-1 holds the value produced dl cycles ago
-            flat = (dl - 1) * pes + sp
-            (route_a if slot == 0 else route_b)[k, pe, flat] = 1.0
+            route[k, pe, slot] = pair_of[dl, sp - pe]
             src_pe[k, pe, slot] = sp
             src_delta[k, pe, slot] = dl
 
     return CGRAProgram(
         mapping=mapping, ii=ii, ring=ring, num_pes=pes,
-        route_a=route_a, route_b=route_b, op_sel=op_sel, imm=imm,
+        route_pairs=np.array(pairs, np.int32).reshape(-1, 2), route=route,
+        op_sel=op_sel, imm=imm,
         op_id=op_id, node_at=node_at, src_pe=src_pe, src_delta=src_delta,
     )
 
@@ -215,9 +231,9 @@ def place_injection(
 
 
 def program_tables(program: CGRAProgram) -> tuple[np.ndarray, ...]:
-    """The kernel's first four operands: the routing, opcode and immediate
-    tables, the immediates as ``[II, 1, pes]``."""
-    return (program.route_a, program.route_b, program.op_sel,
+    """The kernel's first four operands: the route pairs, the route, opcode
+    and immediate tables, the immediates as ``[II, 1, pes]``."""
+    return (program.route_pairs, program.route, program.op_sel,
             program.imm.reshape(program.ii, 1, program.num_pes))
 
 

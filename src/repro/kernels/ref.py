@@ -2,8 +2,8 @@
 
 ``cgra_sim_reference`` executes the same compiled program as the cgra_sim
 kernel but with a structurally different method: integer-indexed reads from
-the full value trace (no ring buffer, no one-hot matmuls), so it validates the
-kernel's routing/ring logic rather than sharing it. Scalar semantics are the
+the full value trace (no ring buffer, no route pairs or shifts), so it
+validates the kernel's routing/ring logic rather than sharing it. Scalar semantics are the
 same ALU as core.simulate (bit-identical in f32 by construction).
 """
 

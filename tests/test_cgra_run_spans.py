@@ -89,7 +89,8 @@ def test_compile_program_emits_lower(gsm, profiled):
         program = compile_program(mapping)
     (lower,) = [e for e in events if e[0] == "lower"]
     assert lower[3] == {"kernel": "gsm", "ii": program.ii, "ring": program.ring,
-                        "pes": 16}
+                        "pes": 16, "route_pairs": len(program.route_pairs),
+                        "table_bytes": sum(t.nbytes for t in program_tables(program))}
 
 
 def test_cgra_run_spans_reach_the_tracer(gsm):

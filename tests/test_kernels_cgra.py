@@ -125,32 +125,44 @@ def test_recurrence_semantics_through_kernel():
 
 def test_vmem_budget_accounting():
     """vmem_bytes is one grid step's footprint, the kernel's vmem_limit_bytes:
-    one kernel step's routing blocks, double-buffered, not all II of them."""
+    one kernel step's route codes, double-buffered, and the ring; it grows
+    as pes, not pes**2."""
     fft = load_suite(["fft"])["fft"]
     res = Compiler(CGRA(20, 20), resolve_options("deterministic-ci", jobs=1)).compile(fft)
     prog = compile_program(res.mapping)
     assert (prog.ii, prog.ring, prog.num_pes) == (7, 7, 400)
     got = prog.vmem_bytes(batch_tile=128)
-    assert got == cgra_sim.vmem_footprint(400, 7, 128)
-    route_block = 400 * 2816 * 4          # [pes, ring*pes], lanes padded to 128
-    assert 4 * route_block < got < 2 * prog.ii * route_block
-    # compiling this program for a v5e needed a 27.25 MiB limit (bisected);
-    # a v5e core has 128 MiB of VMEM
-    assert 27.25 * 2**20 <= got <= 64 * 2**20
+    assert got == cgra_sim.vmem_footprint(400, 7, 128, len(prog.route_pairs))
+    value = 400 * 128 * 4                 # one [pes, bt] f32 slab
+    ring = prog.ring * value
+    assert ring + 2 * value < got < ring + 64 * value
+    # compiling the kernel for a v5e at 20x20, ring 7, batch tile 128 needed
+    # a 5.68 MiB limit (bisected); at 50x50, ring 11, 42.77 MiB, where the
+    # footprint is 56.6 MiB. A v5e core has 128 MiB of VMEM
+    assert 5.68 * 2**20 <= got <= 16 * 2**20
+    assert 42.77 * 2**20 <= cgra_sim.vmem_footprint(2500, 11, 128, 21) <= 64 * 2**20
+    # linear in pes: 2500 PEs cost about 6.25 times what 400 do, and above
+    # UNROLL_PES the number of route pairs costs nothing
+    ratio = cgra_sim.vmem_footprint(2500, 7, 128, 12) / got
+    assert 6 < ratio < 6.5
+    assert cgra_sim.vmem_footprint(400, 7, 128, 60) == got
+    # an unrolled 8x8 kernel with 40 pairs needed 4.88 MiB (bisected)
+    assert cgra_sim.vmem_footprint(64, 11, 128, 40) >= 4.88 * 2**20
 
 
 def test_vmem_over_capacity_is_refused(monkeypatch):
     """On a TPU a program that needs more VMEM than the chip has raises before
     compiling; the batch tile is never shrunk to make it fit."""
-    pes, ring, ii, C, B = 400, 7, 7, 8, 128
-    need = cgra_sim.vmem_footprint(pes, ring, B)
+    pes, ring, ii, C, B, P = 400, 7, 7, 8, 128, 12
+    need = cgra_sim.vmem_footprint(pes, ring, B, P)
     monkeypatch.setattr(cgra_sim.jax, "default_backend", lambda: "tpu")
     monkeypatch.setattr(
         cgra_sim.pltpu, "get_tpu_info",
         lambda: SimpleNamespace(vmem_capacity_bytes=need - 1),
     )
     f32 = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.float32)
-    shapes = [f32((ii, pes, ring * pes)), f32((ii, pes, ring * pes)),
+    i32 = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.int32)
+    shapes = [i32((P, 2)), i32((ii, pes, 2)),
               f32((ii, pes, cgra_sim.NOPS)), f32((ii, 1, pes)),
               f32((C, pes, B)), f32((C, 1, pes))]
     run = functools.partial(cgra_sim.cgra_sim_pallas, ii=ii, ring=ring,

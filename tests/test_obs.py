@@ -308,6 +308,25 @@ def test_anneal_emits_energy_curve_events():
         assert ar is None or 0.0 <= ar <= 1.0
 
 
+def test_anneal_place_spans_carry_search_work():
+    """Each annealing ``place`` call is one ``space.anneal`` span whose
+    moves, restarts and kicks add up to the engine's own counters."""
+    comp = _ci_compiler(space_backend="anneal")
+    tracer = obs.Tracer()
+    with obs.tracing(tracer):
+        res = comp.compile(load_suite(names=["gsm"])["gsm"])
+    assert res.ok
+    spans = [e for e in tracer.events if e["name"] == "space.anneal"]
+    probes = [e for e in tracer.events if e["name"] == "space.probe"]
+    assert spans and len(spans) >= len(probes)
+    for e in spans:
+        assert e["args"]["pes"] == 16
+        assert e["args"]["restarts"] >= 1
+        assert e["args"]["moves"] >= 0 and e["args"]["kicks"] >= 0
+    assert (sum(e["args"]["moves"] for e in spans)
+            == sum(e["args"]["nodes"] for e in probes))
+
+
 def test_session_env_gate(monkeypatch, tmp_path):
     """REPRO_TRACE enables a session with no explicit flag; unset leaves
     the fast path alone."""
@@ -374,3 +393,14 @@ def test_profiler_leaves_chrome_json_unchanged(profiled):
     # the same spans reached the profiler, space probes included
     names = {name for name, *_ in events}
     assert {"compile", "time.probe", "space.probe"} <= names
+
+
+def test_anneal_span_lands_in_profiler_trace(profiled):
+    """With no tracer installed, ``space.anneal`` spans and their search
+    counters land on the profiler's host timeline."""
+    comp = _ci_compiler(space_backend="anneal")
+    with profiled() as events:
+        assert comp.compile(running_example()).ok
+    spans = [stats for name, _, _, stats in events if name == "space.anneal"]
+    assert spans
+    assert all({"pes", "moves", "restarts", "kicks"} <= set(s) for s in spans)
