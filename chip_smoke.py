@@ -104,6 +104,7 @@ def run_phase(name, dfg, fabric, compile_clock, rng) -> dict:
     outs, trace = cgra_run(prog, inputs, ITERS, interpret=False)
     run_s = time.perf_counter() - t0
     compile_s = compile_clock.seconds - compiled_before
+    trace = np.asarray(trace)   # cgra_run leaves it on the device
 
     # the same kernel again on streams and tables already on the device
     inj, active = build_injection(prog, inputs, ITERS)
@@ -148,7 +149,8 @@ def run_phase(name, dfg, fabric, compile_clock, rng) -> dict:
         "run_s": run_s,
         "execute_s": execute_s,
         "bytes_to_device": tables + staged,
-        "bytes_from_device": trace.nbytes,
+        # what cgra_run brings back: the store rows
+        "bytes_from_device": sum(o.nbytes for o in outs.values()),
         "match": "trace == cgra_sim_reference, sampled stores == interpret_dfg",
     }
 

@@ -68,6 +68,6 @@ outs, trace = cgra_run(prog, inputs, num_iters=8, interpret=interpret)
 # read the sink node's stream from the trace (the running example's final op)
 sink = max(dfg.nodes, key=lambda v: m.t_abs[v])
 cycles = [m.t_abs[sink] + it * m.ii for it in range(8)]
-stream = trace[cycles, m.placement[sink], :]
+stream = np.asarray(trace)[cycles, m.placement[sink], :]
 print(f"pallas cgra_sim: sink node {sink} stream {stream.shape} (iters x batch); "
       f"sample: {stream[:3, 0]}")

@@ -15,7 +15,10 @@ kernel reads a dense injection plane ``[C, pes, B]`` that is zero but for one
 row per input node and iteration. ``cgra_run`` ships only those rows, with
 each row's cycle and PE (``stage_injection``), and ``place_injection`` builds
 the plane from them on the device. ``build_injection`` builds the same plane
-on the host: the reference the tests and ``ref.py`` hold it to.
+on the host: the reference the tests and ``ref.py`` hold it to. The kernel's
+trace ``[C, pes, B]`` stays on the device: ``take_stores`` gathers the store
+rows from it there, one per store node and iteration, and only those rows
+come back to the host.
 
 Both record ``repro.obs`` spans, which also land in a JAX profiler trace
 while one is collecting: ``lower`` around ``compile_program``, and
@@ -192,18 +195,30 @@ class StagedInjection(NamedTuple):
                 + self.pe.nbytes + self.active.nbytes)
 
 
+def node_rows(
+    mapping: Mapping, op: str, num_iters: int
+) -> tuple[list[int], np.ndarray, np.ndarray]:
+    """The nodes of opcode ``op`` in graph order, and the (cycle, PE) of each
+    node's row per iteration, node by node: cycle ``t_abs[v] + it * ii``
+    and PE ``placement[v]``, as int32 vectors of ``len(nodes) * num_iters``."""
+    m = mapping
+    nodes = [v for v in m.dfg.nodes if m.dfg.ops[v] == op]
+    its = np.arange(num_iters)
+    cycle = np.array([m.t_abs[v] + it * m.ii for v in nodes for it in its], np.int32)
+    pe = np.repeat(np.array([m.placement[v] for v in nodes], np.int32), num_iters)
+    return nodes, cycle, pe
+
+
 def stage_injection(
     program: CGRAProgram, inputs: dict[int, np.ndarray], num_iters: int
 ) -> StagedInjection:
     """The input values as rows, node by node in graph order, each row's
-    cycle ``t_abs[v] + it * ii`` and PE ``placement[v]``, and the firing
-    mask. The rows are ``inputs[v]`` themselves where they are f32 already."""
+    cycle and PE (``node_rows``), and the firing mask. The rows are
+    ``inputs[v]`` themselves where they are f32 already."""
     m = program.mapping
     its = np.arange(num_iters)
-    nodes = [v for v in m.dfg.nodes if m.dfg.ops[v] == "input"]
+    nodes, cycle, pe = node_rows(m, "input", num_iters)
     rows = tuple(np.asarray(inputs[v][:num_iters], np.float32) for v in nodes)
-    cycle = np.array([m.t_abs[v] + it * m.ii for v in nodes for it in its], np.int32)
-    pe = np.repeat(np.array([m.placement[v] for v in nodes], np.int32), num_iters)
     active = np.zeros((num_cycles(program, num_iters), program.num_pes), np.float32)
     active[np.add.outer(m.t_abs, its * m.ii), np.array(m.placement)[:, None]] = 1.0
     batch = next(iter(inputs.values())).shape[1] if inputs else 1
@@ -228,6 +243,19 @@ def place_injection(
         return plane
     return plane.at[cycle, pe].set(jnp.concatenate(rows), unique_indices=True,
                                    mode="promise_in_bounds")
+
+
+@jax.jit
+def take_stores(
+    trace: jax.Array,   # [C, pes, B] f32, the kernel's output
+    cycle: jax.Array,   # [n_store * iters] int32
+    pe: jax.Array,      # [n_store * iters] int32
+) -> jax.Array:
+    """The store rows ``[n_store * iters, B]`` gathered from the trace on the
+    device, row i at (``cycle[i]``, ``pe[i]``). The indices are traced, so
+    each trace shape compiles once. One row slice at a time: for long rows
+    (B = 51 200) XLA's gather copies the whole trace first, and this does not."""
+    return jax.lax.map(lambda at: trace[at[0], at[1]], (cycle, pe))
 
 
 def program_tables(program: CGRAProgram) -> tuple[np.ndarray, ...]:
@@ -279,8 +307,13 @@ def cgra_run(
     *,
     batch_tile: int = 128,
     interpret: bool = False,
-) -> tuple[dict[int, np.ndarray], np.ndarray]:
+) -> tuple[dict[int, np.ndarray], jax.Array]:
     """Execute on the Pallas kernel; returns (store outputs, full trace).
+
+    The store outputs are host arrays ``[num_iters, B]``, one per store node.
+    The trace ``[C, pes, B]`` is the device array the kernel wrote: it stays
+    on the device, and a caller that reads it converts it
+    (``np.asarray(trace)``).
 
     Compiled for the TPU unless ``interpret=True``; on a CPU backend the
     compiled path raises rather than falling back to the interpreter.
@@ -295,11 +328,14 @@ def cgra_run(
       to the device, and ``place_injection`` building inj there from the
       rows, waited for together (``table_bytes``; ``inj_bytes``: the staged
       bytes sent; ``plane_bytes``: inj and active as the kernel reads them);
-    * ``cgra_run.launch``: the kernel's dispatch (and its compile, if any);
-    * ``cgra_run.wait``: until the kernel's trace is ready;
-    * ``cgra_run.to_host``: the trace back to the host (``bytes``);
-    * ``cgra_run.extract``: the store outputs gathered from the trace
-      (``bytes``).
+    * ``cgra_run.launch``: the kernel's dispatch (and its compile, if any),
+      then ``take_stores``'s, which gathers the store rows on the device (a
+      loop with no store gathers nothing);
+    * ``cgra_run.wait``: until the kernel and the gathered rows are ready;
+    * ``cgra_run.to_host``: the store rows back to the host (``bytes``;
+      ``trace_bytes``: the trace, which stays on the device);
+    * ``cgra_run.extract``: the rows copied and split into one output per
+      store node (``bytes``).
     """
     m = program.mapping
     with obs.span("cgra_run", kernel=m.dfg.name, pes=program.num_pes,
@@ -322,17 +358,19 @@ def cgra_run(
                                   batch=staged.batch)
             operands = jax.block_until_ready((*tables, inj, active))
         with obs.span("cgra_run.launch"):
-            out = _dispatch(program, operands, batch_tile, interpret)
+            trace = _dispatch(program, operands, batch_tile, interpret)
+            stores, store_cycle, store_pe = node_rows(m, "store", num_iters)
+            gathered = (take_stores(trace, store_cycle, store_pe) if stores
+                        else np.empty((0, staged.batch), np.float32))
         with obs.span("cgra_run.wait"):
-            out.block_until_ready()
-        with obs.span("cgra_run.to_host", bytes=out.nbytes):
-            trace = np.asarray(out)
+            jax.block_until_ready((trace, gathered))
+        with obs.span("cgra_run.to_host", bytes=gathered.nbytes,
+                      trace_bytes=trace.nbytes):
+            host = np.asarray(gathered)
 
         with obs.span("cgra_run.extract") as sp:
-            outs: dict[int, np.ndarray] = {}
-            for v in m.dfg.nodes:
-                if m.dfg.ops[v] == "store":
-                    cyc = m.t_abs[v] + np.arange(num_iters) * m.ii
-                    outs[v] = trace[cyc, m.placement[v], :]
-            sp.set(bytes=sum(o.nbytes for o in outs.values()))
+            # the transferred rows are read-only; the outputs are a copy
+            split = np.array(host).reshape(len(stores), num_iters, staged.batch)
+            outs = dict(zip(stores, split))
+            sp.set(bytes=split.nbytes)
     return outs, trace

@@ -3,8 +3,8 @@ profiler trace sees them (CPU, kernel in Pallas interpret mode).
 
 A call splits into six steps, each a span nested under ``cgra_run``, in
 order; the byte attributes equal the arrays' sizes (the host stages and
-sends the input rows, the device builds the planes), and a traced call
-returns what an untraced one does.
+sends the input rows, the device builds the planes, and only the store
+rows come back), and a traced call returns what an untraced one does.
 """
 
 import numpy as np
@@ -66,7 +66,11 @@ def test_cgra_run_spans_nest_in_order(gsm, profiled):
         "table_bytes": sum(t.nbytes for t in program_tables(program)),
         "inj_bytes": staged,
         "plane_bytes": inj.nbytes + active.nbytes}
-    assert got["cgra_run.to_host"] == {"bytes": trace.nbytes}
+    # only the store rows cross; the trace stays on the device
+    assert got["cgra_run.to_host"] == {
+        "bytes": sum(o.nbytes for o in outs.values()),
+        "trace_bytes": trace.nbytes}
+    assert got["cgra_run.to_host"]["bytes"] == 4 * len(outs) * ITERS * STREAMS
     assert got["cgra_run.extract"] == {"bytes": sum(o.nbytes for o in outs.values())}
     assert got["cgra_run.launch"] == got["cgra_run.wait"] == {}
 

@@ -20,7 +20,12 @@ from repro.api import Compiler, resolve_options
 from repro.core import CGRA
 from repro.core.benchsuite import load_suite
 from repro.kernels.cgra_sim import NOPS, cgra_sim_pallas
-from repro.kernels.ops import compile_program, num_cycles, place_injection
+from repro.kernels.ops import (
+    compile_program,
+    num_cycles,
+    place_injection,
+    take_stores,
+)
 
 BATCH, BATCH_TILE, ITERS = 256, 128, 4
 
@@ -125,3 +130,20 @@ def test_injection_plane_builds_for_v5e(fft_program, one_chip):
     plane = 4 * C * (-(-p.num_pes // 8) * 8) * batch     # PEs tiled by 8 rows
     assert mem.output_size_in_bytes == plane
     assert mem.temp_size_in_bytes < plane // 10
+
+
+def test_store_gather_builds_for_v5e(fft_program, one_chip):
+    """``take_stores`` on the farm's trace (64 iterations, 819 200
+    PE-streams a call, 384 streams at 50x50) compiles for the chip, returns
+    only the store rows, and copies no part of the trace: XLA's gather does
+    copy it where rows are long (51 200 streams at 4x4)."""
+    p = fft_program
+    m = p.mapping
+    iters, batch = 64, 384 if p.num_pes == 2500 else 819_200 // p.num_pes
+    C = num_cycles(p, iters)
+    n_store = sum(1 for v in m.dfg.nodes if m.dfg.ops[v] == "store")
+    trace = jax.ShapeDtypeStruct((C, p.num_pes, batch), jnp.float32, sharding=one_chip)
+    index = jax.ShapeDtypeStruct((n_store * iters,), jnp.int32, sharding=one_chip)
+    mem = take_stores.lower(trace, index, index).compile().memory_analysis()
+    assert mem.output_size_in_bytes == 4 * n_store * iters * batch
+    assert mem.temp_size_in_bytes < 4 * C * p.num_pes * batch // 100
