@@ -2,7 +2,6 @@
 
   table3   — II + compile time, decoupled vs joint mapper (paper Tab. III)
   fig5     — compile time vs CGRA size for `aes` (paper Fig. 5)
-  kernels  — Pallas kernel micro-benchmarks
   hetero   — the suite on a heterogeneous arch preset (--arch), with
              execute_mapping capability verification (DESIGN.md §10)
   scale    — one kernel at 4x4..100x100 per space backend (exact vs
@@ -45,8 +44,7 @@ def main(argv=None) -> None:
     )
     ap.add_argument("--skip-joint", action="store_true")
     ap.add_argument("--only",
-                    choices=["table3", "fig5", "kernels", "hetero", "scale",
-                             "service"])
+                    choices=["table3", "fig5", "hetero", "scale", "service"])
     add_cli_args(ap)          # --jobs/--cache-dir/--profile/--arch/... (api)
     args = ap.parse_args(argv)
     if args.smoke:
@@ -69,7 +67,6 @@ def _run_sections(args, options) -> None:
     from benchmarks import (
         bench_fig5,
         bench_hetero,
-        bench_kernels,
         bench_scale,
         bench_table3,
     )
@@ -168,13 +165,6 @@ def _run_sections(args, options) -> None:
             ("service_warm_p99", vrep["warm_p99_ms"] * 1e3,
              f"p50_ms={vrep['warm_p50_ms']};shed_rate={vrep['shed_rate']};"
              f"spec_hits={vrep['speculate']['cold']['speculative_hits']}"))
-
-    if args.only in (None, "kernels"):
-        krows = bench_kernels.run()
-        with open("BENCH_kernels.json", "w") as f:
-            json.dump({"rows": krows}, f, indent=2)
-        for r in krows:
-            csv_rows.append((r["name"], r["us_per_call"], r["derived"]))
 
     print("\nname,us_per_call,derived")
     for name, us, derived in csv_rows:

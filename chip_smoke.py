@@ -35,8 +35,8 @@ import numpy as np  # noqa: E402
 from repro.api import Compiler, resolve_options  # noqa: E402
 from repro.core import CGRA  # noqa: E402
 from repro.core.benchsuite import load_suite, opcode_cover_dfg  # noqa: E402
+from repro.core.opcodes import OPS  # noqa: E402
 from repro.core.simulate import interpret_dfg  # noqa: E402
-from repro.kernels.cgra_sim import KERNEL_OPS  # noqa: E402
 from repro.kernels.ops import (  # noqa: E402
     build_injection,
     cgra_launch,
@@ -78,7 +78,7 @@ def check_trace(prog, got: np.ndarray, want: np.ndarray) -> None:
     if not bad.any():
         return
     c, pe, lane = (int(i[0]) for i in np.nonzero(bad))
-    op = KERNEL_OPS[prog.op_id[c % prog.ii, pe]]
+    op = OPS[prog.op_id[c % prog.ii, pe]].name
     raise AssertionError(
         f"trace differs from cgra_sim_reference at {int(bad.sum())} of "
         f"{bad.size} values; first at cycle {c}, PE {pe}, stream {lane} "

@@ -1,21 +1,18 @@
-"""repro: monomorphism-based CGRA mapping (space/time decoupled) + a
-production-grade multi-pod JAX training/serving framework built around it.
+"""repro: monomorphism-based CGRA mapping (space/time decoupled), whose
+mapped loops execute on a TPU.
 
 Subpackages
 -----------
 api        the stable public compiler surface: Compiler sessions, typed
            CompileOptions profiles, structured CompileResult (DESIGN.md §11)
-core       the paper's mapping algorithm (SMT time + monomorphism space)
-kernels    Pallas TPU kernels (CGRA functional simulator, flash attention)
-models     LM model zoo for the 10 assigned architectures
-configs    one config per architecture, selectable via --arch
-data       sharded input pipelines
-optim      optimizers, LR schedules, gradient compression
-checkpoint sharding-aware async checkpointing
-runtime    fault tolerance, elastic scaling, straggler mitigation
-sharding   logical-axis sharding rules for pjit
-launch     production mesh, multi-pod dry-run, train/serve drivers
-roofline   compiled-artifact roofline analysis
+core       the paper's mapping algorithm (SMT time + monomorphism space),
+           the op table and the host executors
+kernels    the cgra_sim Pallas TPU kernel that executes a mapped loop over
+           many streams, and its lowering
+obs        spans and counters, exported to Perfetto and the JAX profiler
+
+Entry points: ``python -m repro.compile`` (batch compiles) and
+``python -m repro.daemon`` (the resident compile service).
 """
 
 __version__ = "1.1.0"

@@ -4,10 +4,11 @@ The paper's contribution lives here: schedule.py (ASAP/ALAP/MobS/KMS/mII),
 time_smt.py (SMT time solution), space_backends/ (pluggable space solution:
 exact bitset monomorphism + annealing/clustered placement, DESIGN.md §13),
 mapper.py (the decoupled pipeline), baseline.py (joint SAT-MapIt-style
-comparison target), benchsuite.py (Table III DFG suite), simulate.py
-(functional validation), placement.py (the same algorithm placing model stage
-graphs onto TPU pod meshes), arch/ (declarative heterogeneous architecture
-specs: capability classes, topology families, memory ports — DESIGN.md §10).
+comparison target), benchsuite.py (Table III DFG suite), opcodes.py (the op
+table: each opcode's code, arity, capability class and semantics),
+simulate.py (functional validation), arch/ (declarative heterogeneous
+architecture specs: capability classes, topology families, memory ports —
+DESIGN.md §10).
 """
 
 from .arch import ArchSpec, get_preset, list_presets, resolve_arch

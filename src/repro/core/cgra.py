@@ -7,18 +7,18 @@ u→v is spatially routable iff PE(u) is PE(v) itself or a neighbour — regardl
 of the time gap (modulo the II wrap for loop-carried deps). This is what makes
 the paper's space/time decoupling sound, and it is the architecture we model.
 
-``topology`` extends the paper's mesh with three variants: ``torus`` (used
-when the same machinery places computation stage graphs onto TPU pod slices —
-ICI is a torus; see core/placement.py), ``diagonal`` (king-move mesh: the
+``topology`` extends the paper's mesh with three variants: ``torus`` (the
+mesh with wrap-around links), ``diagonal`` (king-move mesh: the
 4-neighbourhood plus diagonals, as in SAT-MapIt-style CGRAs) and ``one-hop``
 (mesh plus distance-2 row/column links).
 
 Heterogeneity (paper §V-3's flagged assumption, lifted here): each PE carries
 a set of *capability classes* — ``alu`` (plain arithmetic/logic), ``mem``
 (loads/stores), ``mul`` (multiply/divide) — and a grid-level memory-port
-count bounds how many memory ops may fire per cycle. The default
-``CGRA(r, c)`` stays the paper's homogeneous grid (every PE every class, no
-port bound); declarative specs live in ``core/arch`` (DESIGN.md §10).
+count bounds how many memory ops may fire per cycle. The op table
+(``core/opcodes.py``) gives each op's class. The default ``CGRA(r, c)``
+stays the paper's homogeneous grid (every PE every class, no port bound);
+declarative specs live in ``core/arch`` (DESIGN.md §10).
 """
 
 from __future__ import annotations
@@ -28,20 +28,13 @@ import json
 from dataclasses import dataclass
 from functools import cached_property
 
+from .opcodes import op_class
+
 # ---------------------------------------------------------------- op classes
 
 #: The capability-class universe. A PE executes an op iff the op's class is in
 #: the PE's class set; ``core/arch`` presets compose grids from these.
 CAP_CLASSES = ("alu", "mem", "mul")
-
-# op -> capability class. Anything not listed (arith/logic/moves/phi/inputs)
-# is plain "alu" work every PE can do.
-_OP_CLASS = {"load": "mem", "store": "mem", "mul": "mul", "div": "mul"}
-
-
-def op_class(op: str) -> str:
-    """Capability class an op needs: ``mem`` | ``mul`` | ``alu``."""
-    return _OP_CLASS.get(op, "alu")
 
 
 class _AdjacencyRow:

@@ -15,31 +15,7 @@ import json
 from dataclasses import dataclass, field
 from typing import Iterable, Mapping, Sequence
 
-# Operation kinds understood by the functional simulator (core/simulate.py) and
-# the cgra_sim Pallas kernel. Arity is used by DFG validation.
-OP_ARITY = {
-    "input": 0,   # live-in (loop invariant or streamed input)
-    "const": 0,
-    "load": 1,    # load base+offset (address operand)
-    "store": 1,   # value operand (address folded into the op immediate)
-    "add": 2,
-    "sub": 2,
-    "mul": 2,
-    "div": 2,
-    "and": 2,
-    "or": 2,
-    "xor": 2,
-    "shl": 2,
-    "shr": 2,
-    "min": 2,
-    "max": 2,
-    "neg": 1,
-    "not": 1,
-    "abs": 1,
-    "mov": 1,     # copy / route-through
-    "phi": 2,     # loop-carried merge
-    "cmp": 2,
-}
+from .opcodes import OP_ARITY
 
 
 @dataclass(frozen=True)
@@ -189,9 +165,7 @@ class DFG:
             if op not in OP_ARITY:
                 raise ValueError(f"{self.name}: unknown op {op!r} at node {v}")
             np_ = len(self.predecessors(v))
-            if op in ("input", "const") and np_ != 0:
-                raise ValueError(f"{self.name}: node {v} ({op}) must have no inputs")
-            if OP_ARITY[op] > 0 and np_ > OP_ARITY[op]:
+            if np_ > OP_ARITY[op]:
                 raise ValueError(
                     f"{self.name}: node {v} ({op}) has {np_} inputs > arity {OP_ARITY[op]}"
                 )

@@ -10,31 +10,27 @@ Two executors over the same ALU semantics:
     closed-adjacent to the consumer PE. Any scheduling/placement bug surfaces
     as a hard error; outputs must match the reference bit-for-bit.
 
-Also provides the opcode table shared with kernels/cgra_sim.py and a
-register-pressure probe (paper §V-3 assumes enough registers; we measure).
+Both evaluate ops with ``alu``, the op table's semantics on Python floats.
+Also provides a register-pressure probe (paper §V-3 assumes enough
+registers; we measure).
 """
 
 from __future__ import annotations
 
 import ctypes
-import math
 from dataclasses import dataclass
+from types import SimpleNamespace
 
 from .cgra import op_class
-from .dfg import DFG, OP_ARITY
+from .dfg import DFG
 from .mapper import Mapping
+from .opcodes import OPCODES, OPS, operands
 
-# Stable opcode numbering shared with the Pallas kernel.
-OPCODES: dict[str, int] = {
-    name: i
-    for i, name in enumerate(
-        [
-            "input", "const", "load", "store", "add", "sub", "mul", "div",
-            "and", "or", "xor", "shl", "shr", "min", "max", "neg", "not",
-            "abs", "mov", "phi", "cmp",
-        ]
-    )
-}
+# ``alu``'s array namespace: one Python float at a time
+_SCALAR = SimpleNamespace(
+    abs=abs, minimum=min, maximum=max, where=lambda c, x, y: x if c else y,
+    broadcast_to=lambda x, shape: x, shape=lambda x: (),
+)
 
 
 def f32(x: float) -> float:
@@ -43,8 +39,9 @@ def f32(x: float) -> float:
     return ctypes.c_float(x).value
 
 
-def alu(op: str, a: float, b: float, imm: float) -> float:
-    """Scalar ALU semantics on float32 words: the result is rounded to f32.
+def alu(op: str, a: float, b: float, imm: float, inj: float = 0.0) -> float:
+    """Scalar ALU: ``op``'s semantics from the op table (``core/opcodes.py``)
+    on Python floats, the result rounded to f32.
 
     Python computes in float64; rounding each result once gives the
     correctly rounded f32 result of +, -, *, / (53 >= 2*24 + 2 bits), so
@@ -53,50 +50,8 @@ def alu(op: str, a: float, b: float, imm: float) -> float:
     value can land just below an integer in f64 and on it in f32, and the
     bitwise ops, which work on 16-bit casts of |x|, then differ by one.
     """
-    return f32(_alu64(op, a, b, imm))
-
-
-def _alu64(op: str, a: float, b: float, imm: float) -> float:
-    ia, ib = int(abs(a)) & 0xFFFF, int(abs(b)) & 0xFFFF
-    if op in ("input", "const"):
-        return imm
-    if op in ("load", "mov", "store"):
-        return a
-    if op == "phi":
-        # loop-carried merge: accumulate (carried operand is 0 on iteration 0),
-        # which makes recurrences semantically live for equivalence testing
-        return a + b
-    if op == "add":
-        return a + b
-    if op == "sub":
-        return a - b
-    if op == "mul":
-        return a * b
-    if op == "div":
-        return a / b if b != 0 else 0.0
-    if op == "and":
-        return float(ia & ib)
-    if op == "or":
-        return float(ia | ib)
-    if op == "xor":
-        return float(ia ^ ib)
-    if op == "shl":
-        return float((ia << (ib % 8)) & 0xFFFF)
-    if op == "shr":
-        return float(ia >> (ib % 8))
-    if op == "min":
-        return min(a, b)
-    if op == "max":
-        return max(a, b)
-    if op == "neg":
-        return -a
-    if op == "not":
-        return float(~ia & 0xFFFF)
-    if op == "abs":
-        return abs(a)
-    if op == "cmp":
-        return 1.0 if a > b else 0.0
-    raise ValueError(f"unknown op {op}")
+    v = operands(a, b, imm, inj, xp=_SCALAR, to_int=int, word=float)
+    return f32(OPS[OPCODES[op]].semantics(v))
 
 
 def _operands(dfg: DFG, v: int) -> list:

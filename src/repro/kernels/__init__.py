@@ -1,14 +1,10 @@
-"""Pallas TPU kernels.
+"""The Pallas TPU kernel that executes mapped CGRA loops.
 
 cgra_sim.py          batched execution of mapped CGRA programs (the paper's
                      compute substrate as a TPU kernel: crossbar -> neighbour
                      shifts and VPU selects, register files -> VMEM ring buffer)
-flash_attention.py   fused attention (causal/sliding-window/softcap/GQA) —
-                     the TPU hot path behind the model zoo's blocked-attention
-                     jnp fallback
-
 ops.py               program compilation + jit'd wrappers
-ref.py               pure-jnp / numpy oracles (kernels assert against these)
+ref.py               the numpy oracle the kernel's trace is held to
 
 The kernels run compiled for the TPU by default. The tests force the CPU
 (JAX_PLATFORMS=cpu) and pass interpret=True; chip_smoke.py at the repo root

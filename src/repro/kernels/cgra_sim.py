@@ -42,13 +42,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# Fixed opcode ordering shared with core.simulate.OPCODES (asserted in ops.py).
-KERNEL_OPS = (
-    "input", "const", "load", "store", "add", "sub", "mul", "div",
-    "and", "or", "xor", "shl", "shr", "min", "max", "neg", "not",
-    "abs", "mov", "phi", "cmp",
-)
-NOPS = len(KERNEL_OPS)
+from repro.core.opcodes import OPS, operands
+
+NOPS = len(OPS)
 
 
 def _residual(a: jax.Array, b: jax.Array, q: jax.Array) -> jax.Array:
@@ -92,35 +88,13 @@ def _round_quotient(a: jax.Array, b: jax.Array, q: jax.Array) -> jax.Array:
 def _alu_all(
     a: jax.Array, b: jax.Array, imm: jax.Array, inj: jax.Array
 ) -> list[jax.Array]:
-    """All candidate op results, one [pes, bt] array per opcode (f32-exact)."""
-    ia = jnp.abs(a).astype(jnp.int32) & 0xFFFF
-    ib = jnp.abs(b).astype(jnp.int32) & 0xFFFF
-    sh = ib % 8
-    safe_b = jnp.where(b != 0, b, 1.0)
-    f = jnp.float32
-    return [
-        inj,                                        # input
-        jnp.broadcast_to(imm, a.shape),             # const
-        a,                                          # load
-        a,                                          # store
-        a + b,                                      # add
-        a - b,                                      # sub
-        a * b,                                      # mul
-        jnp.where(b != 0, _round_quotient(a, safe_b, a / safe_b), 0.0),  # div
-        (ia & ib).astype(f),                        # and
-        (ia | ib).astype(f),                        # or
-        (ia ^ ib).astype(f),                        # xor
-        ((ia << sh) & 0xFFFF).astype(f),            # shl
-        (ia >> sh).astype(f),                       # shr
-        jnp.minimum(a, b),                          # min
-        jnp.maximum(a, b),                          # max
-        -a,                                         # neg
-        (~ia & 0xFFFF).astype(f),                   # not
-        jnp.abs(a),                                 # abs
-        a,                                          # mov
-        a + b,                                      # phi (carried accumulate)
-        (a > b).astype(f),                          # cmp
-    ]
+    """All candidate op results, one [pes, bt] array per opcode in code
+    order: the op table's semantics on jnp blocks (f32-exact), with the
+    quotient rounded as IEEE division rounds it."""
+    v = operands(a, b, imm, inj, xp=jnp, to_int=lambda x: x.astype(jnp.int32),
+                 word=lambda x: x.astype(jnp.float32),
+                 divide=lambda a, b: _round_quotient(a, b, a / b))
+    return [op.semantics(v) for op in OPS]
 
 
 def _cgra_sim_kernel(

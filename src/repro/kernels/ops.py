@@ -41,11 +41,10 @@ import numpy as np
 from repro import obs
 from repro.core.dfg import DFG
 from repro.core.mapper import Mapping
-from repro.core.simulate import OPCODES, _operands
+from repro.core.opcodes import OPCODES
+from repro.core.simulate import _operands
 
-from .cgra_sim import KERNEL_OPS, NOPS, cgra_sim_pallas, vmem_footprint
-
-assert list(KERNEL_OPS) == list(OPCODES), "kernel/oracle opcode tables diverged"
+from .cgra_sim import NOPS, cgra_sim_pallas, vmem_footprint
 
 
 @dataclass

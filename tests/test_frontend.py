@@ -1,11 +1,10 @@
-"""Tracing frontend + expert-group placement tests."""
+"""Tracing frontend tests."""
 
 import numpy as np
 import pytest
 
 from repro.core import CGRA, map_dfg
 from repro.core.frontend import trace_loop
-from repro.core.placement import expert_groups_graph, place_stages
 from repro.core.simulate import check_equivalence, interpret_dfg
 
 
@@ -54,11 +53,3 @@ def test_trace_rejects_bad_carried():
     with pytest.raises(ValueError):
         trace_loop(lambda ins, c: ([ins[0]], {"other": ins[0]}),
                    num_inputs=1, carried=["acc"])
-
-
-def test_expert_group_placement_single_hop():
-    g = expert_groups_graph(16, heavy_routes=[(0, 5), (2, 9), (7, 12)])
-    placement = place_stages(g, (4, 4))
-    assert placement is not None
-    assert placement.single_hop_fraction() == 1.0
-    assert len(set(placement.stage_to_device)) == 16
